@@ -200,7 +200,17 @@ def fault_rows(prog, in_shape, imgs, healthy_dt: float) -> None:
 
     # process isolation overhead (informational): the same supervised wave
     # through one in-process worker vs one actor process behind the
-    # unix-socket RPC tier; the delta is the pickle + frame round-trip
+    # unix-socket RPC tier; the delta is the pickle + frame round-trip.
+    # This process has served in-process above and so holds its devices;
+    # an accelerator chip belongs to one process, so the actor row runs
+    # only where devices can be shared (CPU)
+    import jax
+
+    if jax.default_backend() != "cpu":
+        print(f"# serving/lenet5_process_isolation skipped: this process "
+              f"holds the {jax.default_backend()} device its worker would "
+              f"need (run launch/serve.py --isolation process instead)")
+        return
     import inspect
 
     from repro.runtime.actor import cnn_program_factory

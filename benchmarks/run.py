@@ -37,6 +37,9 @@ def main() -> None:
         bench_memory, bench_patterns, bench_ratio, bench_resources,
         bench_roofline, bench_serving,
     )
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     mods = {

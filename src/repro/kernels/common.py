@@ -1,13 +1,18 @@
 """Shared kernel plumbing: interpret-mode switch + padding helpers.
 
-TARGET is TPU (Mosaic); on this CPU-only container every kernel runs with
-``interpret=True``, which executes the kernel body in Python for correctness
-validation against the pure-jnp oracles in ref.py.
+The kernels target the TPU: there Pallas lowers them through Mosaic and
+``interpret_mode()`` is False.  On any other backend (the CPU test lane)
+they run with ``interpret=True``, which executes the kernel body in Python
+for correctness validation against the pure-jnp oracles in ref.py.  Kernel
+modules read the switch as ``common.interpret_mode()`` so a test can steer
+every kernel at once (tests/test_tpu_compile.py compiles them for a
+described TPU from a CPU process).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
 
 
 def interpret_mode() -> bool:
@@ -88,21 +93,44 @@ def gemm_residual_fusable(x, w, res) -> bool:
             and getattr(res, "shape", None) == (*x.shape[:-1], w.shape[1]))
 
 
-def conv_tap(img, oh_block_id, kh, kw, *, stride, boh, wo):
-    """The (boh*wo, C) tile of tap (kh, kw) for one output-row block, carved
-    from a VMEM-resident padded (Hp, Wp, C) image — the shared implicit-
-    im2col slice of the depthwise and pooling kernels (fused_conv inlines
-    the same arithmetic with its channel-block contraction)."""
-    row0 = oh_block_id * (boh * stride) + kh
-    span_h = (boh - 1) * stride + 1
-    span_w = (wo - 1) * stride + 1
-    rows = jax.lax.dynamic_slice(
-        img, (row0, 0, 0), (span_h, img.shape[1], img.shape[2])
-    )[::stride]
-    patch = jax.lax.dynamic_slice(
-        rows, (0, kw, 0), (boh, span_w, img.shape[2])
-    )[:, ::stride]
-    return patch.reshape(boh * wo, img.shape[2])
+def stride_phases(x_p, sh: int, sw: int):
+    """(N, Hp, Wp, C) -> (N, sh*sw, ceil(Hp/sh), ceil(Wp/sw), C): the
+    padded image split into its stride phases, phase ``(i % sh) * sw +
+    (j % sw)`` holding rows ``i::sh`` and columns ``j::sw``.  A strided tap
+    then reads a contiguous window of one phase, so the kernels never issue
+    a strided or dynamically-offset sublane load (Mosaic has neither for
+    8/16-bit data)."""
+    n, hp, wp, c = x_p.shape
+    hq, wq = -(-hp // sh), -(-wp // sw)
+    x_p = jnp.pad(x_p, ((0, 0), (0, hq * sh - hp), (0, wq * sw - wp), (0, 0)))
+    x = x_p.reshape(n, hq, sh, wq, sw, c).transpose(0, 2, 4, 1, 3, 5)
+    return x.reshape(n, sh * sw, hq, wq, c)
+
+
+def conv_tap(x_ref, row0, kh: int, kw: int, *, sh: int, sw: int, boh: int,
+             wo: int):
+    """The (boh*wo, C) tile of static tap (kh, kw) for the output-row block
+    starting at (traced) output row ``row0``, read straight from the
+    VMEM-resident phase-split image ``x_ref`` of block shape
+    (1, sh*sw, Hq, Wq, C) — the shared implicit-im2col slice of the conv,
+    depthwise and pooling kernels.  Only the leading (row) offset is
+    dynamic; the column offset ``kw // sw`` is static."""
+    tile = x_ref[0, (kh % sh) * sw + kw % sw,
+                 pl.ds(row0 + kh // sh, boh), pl.ds(kw // sw, wo), :]
+    return tile.reshape(boh * wo, tile.shape[-1])
+
+
+def padded_phases(x, top: int, left: int, hp_req: int, wp_req: int,
+                  stride: int, c_block: int):
+    """Zero-pad (exact for symmetric int8, and only feeding discarded
+    outputs for VALID pools) so every tap is in bounds (extents from
+    :func:`conv_tile_plan`), pad channels to ``c_block``, and split into
+    stride phases."""
+    _, h, w_in, _ = x.shape
+    x_p = jnp.pad(x, ((0, 0), (top, max(hp_req - h - top, 0)),
+                      (left, max(wp_req - w_in - left, 0)), (0, 0)))
+    x_p, _ = pad_to(x_p, 3, c_block)
+    return stride_phases(x_p, stride, stride)
 
 
 def pad_to(x: jax.Array, axis: int, multiple: int, value=0.0):

@@ -10,10 +10,11 @@ same pre-folded dequant + bias + folded-BN + relu/relu6 epilogue as
 ``fused_conv`` is applied in-register before the single HBM write.
 
 :func:`depthwise_conv_int8` — the standalone depthwise kernel.  Grid
-``(n, oh_block, c_block, kh, kw)``: the (kh, kw) contraction dims are
-innermost so a ``(BM, BC)`` int32 accumulator carries across the taps; the
-activation tile for each tap is carved out of the VMEM-resident padded image
-(same implicit-im2col slicing as fused_conv, minus the channel contraction).
+``(n, oh_block, c_block)``: the (kh, kw) taps are unrolled inside each step
+into a ``(BM, BC)`` int32 accumulator; the activation tile for each tap is
+carved out of the VMEM-resident, stride-phase-split padded image (same
+implicit-im2col slicing as fused_conv, minus the channel contraction).  The
+tap weights travel as a ``(KH*KW, 1, C)`` stack so each tap is a lane row.
 
 :func:`sep_block_int8` — the fused separable block (dw -> 1x1 pw) that the
 mobile models emit as ONE dispatch site.  The depthwise output tile never
@@ -36,50 +37,46 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import common
 from repro.kernels.common import (
-    EPILOGUE_ACTS, conv_tap, conv_tile_plan, interpret_mode, pad_to,
+    EPILOGUE_ACTS, conv_tap, conv_tile_plan, pad_to, padded_phases,
 )
 
 BM, BN, BC = 128, 128, 128
 
 _ACTS = EPILOGUE_ACTS
 
-# the shared implicit-im2col tap slice (also used by the pooling kernels)
-_dw_patch = conv_tap
+
+def _dw_taps(x_ref, w_ref, *, kh, kw, stride, boh, wo):
+    """int32 per-channel MAC over the static (kh, kw) taps of this output
+    block: one int8 tap per lane, no channel contraction (the VPU form of
+    the mac_matmul pattern)."""
+    row0 = pl.program_id(1) * boh
+    acc = None
+    for i in range(kh):
+        for j in range(kw):
+            patch = conv_tap(x_ref, row0, i, j, sh=stride, sw=stride,
+                             boh=boh, wo=wo).astype(jnp.int32)
+            term = patch * w_ref[i * kw + j].astype(jnp.int32)
+            acc = term if acc is None else acc + term
+    return acc
 
 
-def _dw_kernel(x_ref, w_ref, es_ref, eb_ref, o_ref, acc_ref, *,
-               stride, boh, wo, act):
-    # grid: (n, oh_block, c_block, kh, kw); the (kh, kw) taps are innermost
-    # so the int32 accumulator carries across them
-    kh, kw = pl.program_id(3), pl.program_id(4)
-
-    @pl.when((kh == 0) & (kw == 0))
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    img = x_ref[0]  # (Hp, Wp, BC) int8
-    patch = _dw_patch(img, pl.program_id(1), kh, kw,
-                      stride=stride, boh=boh, wo=wo)
-    # per-channel MAC: one int8 tap per lane, accumulated in int32 (VPU form
-    # of the mac_matmul pattern — no channel contraction)
-    acc_ref[...] += patch.astype(jnp.int32) * w_ref[0, 0].astype(jnp.int32)
-
-    @pl.when((kh == pl.num_programs(3) - 1) & (kw == pl.num_programs(4) - 1))
-    def _epilogue():
-        # dequant + bias + folded-BN affine pre-folded into (es, eb)
-        y = acc_ref[...].astype(jnp.float32) * es_ref[...] + eb_ref[...]
-        o_ref[0] = _ACTS[act](y).reshape(boh, wo, -1).astype(o_ref.dtype)
+def _dw_kernel(x_ref, w_ref, es_ref, eb_ref, o_ref, *,
+               kh, kw, stride, boh, wo, act):
+    # grid: (n, oh_block, c_block); the taps are unrolled in _dw_taps
+    acc = _dw_taps(x_ref, w_ref, kh=kh, kw=kw, stride=stride, boh=boh, wo=wo)
+    # dequant + bias + folded-BN affine pre-folded into (es, eb)
+    y = acc.astype(jnp.float32) * es_ref[...] + eb_ref[...]
+    o_ref[0] = _ACTS[act](y).reshape(boh, wo, -1).astype(o_ref.dtype)
 
 
-def _padded_image(x_int8, top, left, hp_req, wp_req, bc=BC):
-    """Zero-pad (exact for symmetric int8) so every tap slice is in bounds
-    (extents from :func:`repro.kernels.common.conv_tile_plan`)."""
-    _, h, w_in, _ = x_int8.shape
-    x_p = jnp.pad(x_int8, ((0, 0), (top, max(hp_req - h - top, 0)),
-                           (left, max(wp_req - w_in - left, 0)), (0, 0)))
-    x_p, _ = pad_to(x_p, 3, bc)
-    return x_p
+def _tap_stack(w_int8, bc):
+    """(KH, KW, C) taps -> (KH*KW, 1, Cp): one lane row per tap, so a
+    channel block is a ``(KH*KW, 1, bc)`` block (full trailing dims)."""
+    kh, kw, c = w_int8.shape
+    w, _ = pad_to(w_int8.reshape(kh * kw, 1, c), 2, bc)
+    return w
 
 
 @functools.partial(jax.jit, static_argnames=("stride", "padding", "act",
@@ -99,29 +96,29 @@ def depthwise_conv_int8(x_int8, w_int8, eff_scale, eff_bias, *, stride=1,
     ho, wo, boh, ohb, top, left, hp_req, wp_req = conv_tile_plan(
         h, w_in, kh, kw, stride, padding, bm
     )
-    x_p = _padded_image(x_int8, top, left, hp_req, wp_req, bc)
-    w_p, _ = pad_to(w_int8, 2, bc)
+    x_ph = padded_phases(x_int8, top, left, hp_req, wp_req, stride, bc)
+    w_p = _tap_stack(w_int8, bc)
     es, _ = pad_to(eff_scale.reshape(1, -1).astype(jnp.float32), 1, bc)
     eb, _ = pad_to(eff_bias.reshape(1, -1).astype(jnp.float32), 1, bc)
-    _, hp, wp, cp = x_p.shape
+    _, nph, hq, wq, cp = x_ph.shape
     out = pl.pallas_call(
-        functools.partial(_dw_kernel, stride=stride, boh=boh, wo=wo, act=act),
-        grid=(n, ohb, cp // bc, kh, kw),
+        functools.partial(_dw_kernel, kh=kh, kw=kw, stride=stride, boh=boh,
+                          wo=wo, act=act),
+        grid=(n, ohb, cp // bc),
         in_specs=[
-            pl.BlockSpec((1, hp, wp, bc),
-                         lambda ni, oi, ci, khi, kwi: (ni, 0, 0, ci)),
-            pl.BlockSpec((1, 1, bc),
-                         lambda ni, oi, ci, khi, kwi: (khi, kwi, ci)),
-            pl.BlockSpec((1, bc), lambda ni, oi, ci, khi, kwi: (0, ci)),
-            pl.BlockSpec((1, bc), lambda ni, oi, ci, khi, kwi: (0, ci)),
+            pl.BlockSpec((1, nph, hq, wq, bc),
+                         lambda ni, oi, ci: (ni, 0, 0, 0, ci)),
+            pl.BlockSpec((kh * kw, 1, bc), lambda ni, oi, ci: (0, 0, ci)),
+            pl.BlockSpec((1, bc), lambda ni, oi, ci: (0, ci)),
+            pl.BlockSpec((1, bc), lambda ni, oi, ci: (0, ci)),
         ],
         out_specs=pl.BlockSpec(
-            (1, boh, wo, bc), lambda ni, oi, ci, khi, kwi: (ni, oi, 0, ci)
+            (1, boh, wo, bc), lambda ni, oi, ci: (ni, oi, 0, ci)
         ),
         out_shape=jax.ShapeDtypeStruct((n, ohb * boh, wo, cp), out_dtype),
-        scratch_shapes=[pltpu.VMEM((boh * wo, bc), jnp.int32)],
-        interpret=interpret_mode(),
-    )(x_p, w_p, es, eb)
+        name="depthwise_conv",
+        interpret=common.interpret_mode(),
+    )(x_ph, w_p, es, eb)
     return out[:, :ho, :, :c]
 
 
@@ -135,15 +132,9 @@ def _sep_kernel(x_ref, wd_ref, ds_ref, db_ref, wp_ref, ps_ref, pb_ref,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    img = x_ref[0]  # (Hp, Wp, BC) int8
     # depthwise tile for this cin block, taps unrolled (KH, KW static) —
     # int32 MAC in registers, never written to HBM
-    dw = jnp.zeros((acc_ref.shape[0], img.shape[2]), jnp.int32)
-    for khi in range(kh):
-        for kwi in range(kw):
-            patch = _dw_patch(img, pl.program_id(1), khi, kwi,
-                              stride=stride, boh=boh, wo=wo)
-            dw += patch.astype(jnp.int32) * wd_ref[khi, kwi].astype(jnp.int32)
+    dw = _dw_taps(x_ref, wd_ref, kh=kh, kw=kw, stride=stride, boh=boh, wo=wo)
     # depthwise epilogue in-register (dequant + bias + folded BN + act) ...
     dwf = _ACTS[dw_act](dw.astype(jnp.float32) * ds_ref[...] + db_ref[...])
     # ... feeds the MXU pointwise contraction directly from VMEM
@@ -184,24 +175,24 @@ def sep_block_int8(x_int8, w_dw_int8, dw_scale, dw_bias, w_pw_int8,
     ho, wo, boh, ohb, top, left, hp_req, wp_req = conv_tile_plan(
         h, w_in, kh, kw, stride, padding, bm
     )
-    x_p = _padded_image(x_int8, top, left, hp_req, wp_req, bc)
-    wd, _ = pad_to(w_dw_int8, 2, bc)
+    x_ph = padded_phases(x_int8, top, left, hp_req, wp_req, stride, bc)
+    wd = _tap_stack(w_dw_int8, bc)
     ds, _ = pad_to(dw_scale.reshape(1, -1).astype(jnp.float32), 1, bc)
     db, _ = pad_to(dw_bias.reshape(1, -1).astype(jnp.float32), 1, bc)
     wp, _ = pad_to(w_pw_int8, 0, bc)
     wp, _ = pad_to(wp, 1, bn)
     ps, _ = pad_to(pw_scale.reshape(1, -1).astype(jnp.float32), 1, bn)
     pb, _ = pad_to(pw_bias.reshape(1, -1).astype(jnp.float32), 1, bn)
-    _, hp, wp_sp, cp = x_p.shape
+    _, nph, hq, wq, cp = x_ph.shape
     nb = wp.shape[1] // bn
     out = pl.pallas_call(
         functools.partial(_sep_kernel, stride=stride, boh=boh, wo=wo,
                           kh=kh, kw=kw, dw_act=dw_act, pw_act=pw_act),
         grid=(n, ohb, nb, cp // bc),
         in_specs=[
-            pl.BlockSpec((1, hp, wp_sp, bc),
-                         lambda ni, oi, nbi, ci: (ni, 0, 0, ci)),
-            pl.BlockSpec((kh, kw, bc), lambda ni, oi, nbi, ci: (0, 0, ci)),
+            pl.BlockSpec((1, nph, hq, wq, bc),
+                         lambda ni, oi, nbi, ci: (ni, 0, 0, 0, ci)),
+            pl.BlockSpec((kh * kw, 1, bc), lambda ni, oi, nbi, ci: (0, 0, ci)),
             pl.BlockSpec((1, bc), lambda ni, oi, nbi, ci: (0, ci)),
             pl.BlockSpec((1, bc), lambda ni, oi, nbi, ci: (0, ci)),
             pl.BlockSpec((bc, bn), lambda ni, oi, nbi, ci: (ci, nbi)),
@@ -213,6 +204,7 @@ def sep_block_int8(x_int8, w_dw_int8, dw_scale, dw_bias, w_pw_int8,
         ),
         out_shape=jax.ShapeDtypeStruct((n, ohb * boh, wo, nb * bn), out_dtype),
         scratch_shapes=[pltpu.VMEM((boh * wo, bn), jnp.float32)],
-        interpret=interpret_mode(),
-    )(x_p, wd, ds, db, wp, ps, pb)
+        name="sep_block",
+        interpret=common.interpret_mode(),
+    )(x_ph, wd, ds, db, wp, ps, pb)
     return out[:, :ho, :, :cout]

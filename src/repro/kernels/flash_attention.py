@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_mode
+from repro.kernels import common
 
 NEG_INF = -1e30
 
@@ -101,5 +101,6 @@ def flash_attention(q, k, v, causal=True, bq=128, bk=128):
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, 1), jnp.float32),
         ],
-        interpret=interpret_mode(),
+        name="flash_attention",
+        interpret=common.interpret_mode(),
     )(q, k, v)

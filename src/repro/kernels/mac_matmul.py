@@ -21,7 +21,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import interpret_mode, pad_to
+from repro.kernels import common
+from repro.kernels.common import pad_to
 
 BM, BN, BK = 128, 128, 128
 
@@ -66,7 +67,8 @@ def mac_matmul_int8(x_int8, w_int8, scale, out_dtype=jnp.float32):
         out_specs=pl.BlockSpec((BM, BN), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[_vmem((BM, BN), jnp.int32)],
-        interpret=interpret_mode(),
+        name="mac_matmul_int8",
+        interpret=common.interpret_mode(),
     )(x_int8, w_int8, scale)
     return out[:M, :N]
 

@@ -14,7 +14,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_mode, pad_to
+from repro.kernels import common
+from repro.kernels.common import pad_to
 
 BM, BN, BK = 128, 128, 128
 
@@ -109,6 +110,7 @@ def matmul_epilogue(x, w, b=None, act="none", scale=None, shift=None,
         out_specs=pl.BlockSpec((bm, bn), lambda m, n, k: (m, n)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), x.dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        interpret=interpret_mode(),
+        name="matmul_epilogue",
+        interpret=common.interpret_mode(),
     )(*operands)
     return out[:M, :N].reshape(*orig_shape[:-1], N)

@@ -16,6 +16,8 @@ which is correctness emulation, not a serving path.
 """
 from __future__ import annotations
 
+import collections
+
 import jax.numpy as jnp
 
 from repro.core import dispatch
@@ -34,6 +36,16 @@ from repro.kernels.common import (
     gemm_residual_fusable, pad_to,
 )
 from repro.models.layers import _flash_attention_ref
+
+# dispatch sites whose wrapper took its jnp-reference branch (a kernel lost
+# to a guard), counted per pattern at trace time; chip_smoke.py requires
+# zero on the model it serves
+REF_FALLBACKS: collections.Counter = collections.Counter()
+
+
+def _ref_fallback(pattern, fn, *args, **kwargs):
+    REF_FALLBACKS[pattern] += 1
+    return fn(*args, **kwargs)
 
 
 def _pallas_mac_matmul_int8(x, quant):
@@ -69,9 +81,10 @@ def _pallas_fused_conv(x, w, b=None, *, stride=1, padding="SAME", groups=1,
         act=act,
     )
     if not eligible or not res_ok:
-        return ref.fused_conv_ref(
-            x, w, b, stride=stride, padding=padding, groups=groups, act=act,
-            scale=scale, shift=shift, residual=residual,
+        return _ref_fallback(
+            "fused_conv", ref.fused_conv_ref, x, w, b, stride=stride,
+            padding=padding, groups=groups, act=act, scale=scale,
+            shift=shift, residual=residual,
         )
     # dynamic per-tensor activation quant + per-output-channel weight quant
     # (paper: full int8 inference; dequant folds into the kernel epilogue)
@@ -131,9 +144,10 @@ def _pallas_depthwise_conv(x, w, b=None, *, stride=1, padding="SAME",
         if (x.ndim == 4 and getattr(w, "ndim", 0) == 4 and w.shape[2]
                 and x.shape[-1] % w.shape[2] == 0):
             groups = x.shape[-1] // w.shape[2]
-        return ref.fused_conv_ref(
-            x, w, b, stride=stride, padding=padding, groups=groups, act=act,
-            scale=scale, shift=shift,
+        return _ref_fallback(
+            "depthwise_conv", ref.fused_conv_ref, x, w, b, stride=stride,
+            padding=padding, groups=groups, act=act, scale=scale,
+            shift=shift,
         )
     c = x.shape[-1]
     x_int8, xs = _quant_int8(x)
@@ -167,6 +181,7 @@ def _pallas_sep_block(x, w_dw, w_pw, *, stride=1, padding="SAME",
             or padding not in ("SAME", "VALID")
             or dw_act not in dw._ACTS or pw_act not in dw._ACTS
             or _dw_degenerate(x, w_dw, stride, padding)):
+        REF_FALLBACKS["sep_block"] += 1  # the fused block is lost
         y = _pallas_depthwise_conv(x, w_dw, None, stride=stride,
                                    padding=padding, act=dw_act,
                                    scale=dw_scale, shift=dw_shift)
@@ -198,8 +213,9 @@ def _pallas_matmul_epilogue(x, w, b=None, act="none", scale=None, shift=None,
                             residual=None):
     if residual is not None and not gemm_residual_fusable(x, w, residual):
         # mis-shaped skip tensor: stay on the algorithmically-fused oracle
-        return ref.matmul_epilogue_ref(x, w, b, act=act, scale=scale,
-                                       shift=shift, residual=residual)
+        return _ref_fallback("matmul_epilogue", ref.matmul_epilogue_ref,
+                             x, w, b, act=act, scale=scale, shift=shift,
+                             residual=residual)
     cfg = tuning.lookup("matmul_epilogue",
                         tuning.gemm_dims(x.shape, w.shape))
     return me.matmul_epilogue(x, w, b, act=act, scale=scale, shift=shift,
@@ -216,7 +232,8 @@ def _pallas_pool(x, *, op, k=2, stride=2):
     (still one dispatch site; the cost model owns the perf delta).
     """
     if not pk.fast_path_supported(x, op=op, k=k, stride=stride):
-        return ref.pool_ref(x, op=op, k=k, stride=stride)
+        return _ref_fallback("pool", ref.pool_ref, x, op=op, k=k,
+                             stride=stride)
     if op == "global_avg":
         return pk.global_avgpool(x)
     if op == "max":
@@ -245,10 +262,10 @@ def _pallas_flash_attention(q, k, v, *, causal=True, q_offset=0,
             or pad_unsafe:
         # decode (Sq==1), ragged decode, windows, cross-attention: ref path
         # (which also dequants int8 KV when k_scale is set)
-        return _flash_attention_ref(
-            q, k, v, causal=causal, q_offset=q_offset, impl=impl,
-            chunk=chunk, window=window, kv_len=kv_len,
-            k_scale=k_scale, v_scale=v_scale,
+        return _ref_fallback(
+            "flash_attention", _flash_attention_ref, q, k, v, causal=causal,
+            q_offset=q_offset, impl=impl, chunk=chunk, window=window,
+            kv_len=kv_len, k_scale=k_scale, v_scale=v_scale,
         )
     if k_scale is not None:
         # int8-KV dequant path (zol v4): the serving tier stores KV as int8

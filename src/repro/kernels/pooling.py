@@ -30,8 +30,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import common
 from repro.kernels.common import (
-    conv_out_size, conv_tap as _tap, conv_tile_plan, interpret_mode, pad_to,
+    conv_out_size, conv_tap, conv_tile_plan, pad_to, padded_phases,
 )
 
 BM, BC = 128, 128
@@ -82,16 +83,16 @@ def fast_path_supported(x, *, op, k=2, stride=2) -> bool:
 def _pool_kernel(x_ref, o_ref, *, k, stride, boh, wo, op):
     # grid: (n, oh_block, c_block); the k*k taps are unrolled (k is static
     # and tiny), so the whole reduce + rescale happens in registers
-    img = x_ref[0]  # (Hp, Wp, BC)
-    acc = _tap(img, pl.program_id(1), 0, 0,
-               stride=stride, boh=boh, wo=wo).astype(jnp.float32)
+    row0 = pl.program_id(1) * boh
+    acc = None
     for kh in range(k):
         for kw in range(k):
-            if kh == 0 and kw == 0:
-                continue
-            t = _tap(img, pl.program_id(1), kh, kw,
-                     stride=stride, boh=boh, wo=wo).astype(jnp.float32)
-            acc = jnp.maximum(acc, t) if op == "max" else acc + t
+            t = conv_tap(x_ref, row0, kh, kw, sh=stride, sw=stride, boh=boh,
+                         wo=wo).astype(jnp.float32)
+            if acc is None:
+                acc = t
+            else:
+                acc = jnp.maximum(acc, t) if op == "max" else acc + t
     if op == "avg":
         acc = acc * (1.0 / (k * k))  # the rescale never round-trips HBM
     o_ref[0] = acc.reshape(boh, wo, -1).astype(o_ref.dtype)
@@ -100,8 +101,8 @@ def _pool_kernel(x_ref, o_ref, *, k, stride, boh, wo, op):
 def _gap_kernel(x_ref, o_ref, *, hw):
     # grid: (n, c_block); one pass over the full (H, W, BC) image per lane
     img = x_ref[0].astype(jnp.float32)
-    o_ref[...] = (jnp.sum(img, axis=(0, 1), keepdims=False)[None, :]
-                  * (1.0 / hw)).astype(o_ref.dtype)
+    o_ref[0] = (jnp.sum(jnp.sum(img, axis=0), axis=0, keepdims=True)
+                * (1.0 / hw)).astype(o_ref.dtype)
 
 
 def _avg_out_dtype(dtype):
@@ -115,24 +116,24 @@ def _windowed_pool(x, k, stride, op):
     )
     # rows/cols beyond the VALID extent only feed discarded output rows
     # (sliced off below), so the zero pad value never reaches a kept output
-    x_p = jnp.pad(x, ((0, 0), (0, max(hp_req - h, 0)),
-                      (0, max(wp_req - w_in, 0)), (0, 0)))
-    x_p, _ = pad_to(x_p, 3, BC)
-    _, hp, wp, cp = x_p.shape
+    x_ph = padded_phases(x, 0, 0, hp_req, wp_req, stride, BC)
+    _, nph, hq, wq, cp = x_ph.shape
     out_dtype = x.dtype if op == "max" else _avg_out_dtype(x.dtype)
     out = pl.pallas_call(
         functools.partial(_pool_kernel, k=k, stride=stride, boh=boh, wo=wo,
                           op=op),
         grid=(n, ohb, cp // BC),
         in_specs=[
-            pl.BlockSpec((1, hp, wp, BC), lambda ni, oi, ci: (ni, 0, 0, ci)),
+            pl.BlockSpec((1, nph, hq, wq, BC),
+                         lambda ni, oi, ci: (ni, 0, 0, 0, ci)),
         ],
         out_specs=pl.BlockSpec(
             (1, boh, wo, BC), lambda ni, oi, ci: (ni, oi, 0, ci)
         ),
         out_shape=jax.ShapeDtypeStruct((n, ohb * boh, wo, cp), out_dtype),
-        interpret=interpret_mode(),
-    )(x_p)
+        name=f"{op}pool",
+        interpret=common.interpret_mode(),
+    )(x_ph)
     return out[:, :ho, :, :c]
 
 
@@ -157,14 +158,16 @@ def global_avgpool(x):
     n, h, w_in, c = x.shape
     x_p, _ = pad_to(x, 3, BC)
     cp = x_p.shape[3]
+    # (N, 1, Cp) output: a (1, 1, BC) block has full trailing dims
     out = pl.pallas_call(
         functools.partial(_gap_kernel, hw=h * w_in),
         grid=(n, cp // BC),
         in_specs=[
             pl.BlockSpec((1, h, w_in, BC), lambda ni, ci: (ni, 0, 0, ci)),
         ],
-        out_specs=pl.BlockSpec((1, BC), lambda ni, ci: (ni, ci)),
-        out_shape=jax.ShapeDtypeStruct((n, cp), _avg_out_dtype(x.dtype)),
-        interpret=interpret_mode(),
+        out_specs=pl.BlockSpec((1, 1, BC), lambda ni, ci: (ni, 0, ci)),
+        out_shape=jax.ShapeDtypeStruct((n, 1, cp), _avg_out_dtype(x.dtype)),
+        name="global_avgpool",
+        interpret=common.interpret_mode(),
     )(x_p)
-    return out[:, :c]
+    return out[:, 0, :c]

@@ -17,9 +17,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.common import interpret_mode, pad_to
+from repro.kernels import common
+from repro.kernels.common import pad_to
 
-BR = 256  # rows per block
+BR = 256  # rows per block (at most)
+# elements per (rows, D) buffer: four such arrays, double-buffered, stay
+# within half of v5e's 16 MiB default scoped VMEM in f32
+_BLOCK_ELEMS = 1 << 18
 
 
 def _kernel(res_ref, x_ref, scale_ref, newres_ref, normed_ref, *, eps):
@@ -37,25 +41,27 @@ def residual_rmsnorm(res, x, scale, eps=1e-6):
     D = orig_shape[-1]
     r2 = res.reshape(-1, D)
     x2 = x.reshape(-1, D)
-    r2, R = pad_to(r2, 0, BR)
-    x2, _ = pad_to(x2, 0, BR)
+    br = max(8, min(BR, _BLOCK_ELEMS // D // 8 * 8))
+    r2, R = pad_to(r2, 0, br)
+    x2, _ = pad_to(x2, 0, br)
     Rp = r2.shape[0]
     newres, normed = pl.pallas_call(
         functools.partial(_kernel, eps=eps),
-        grid=(Rp // BR,),
+        grid=(Rp // br,),
         in_specs=[
-            pl.BlockSpec((BR, D), lambda r: (r, 0)),
-            pl.BlockSpec((BR, D), lambda r: (r, 0)),
+            pl.BlockSpec((br, D), lambda r: (r, 0)),
+            pl.BlockSpec((br, D), lambda r: (r, 0)),
             pl.BlockSpec((1, D), lambda r: (0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((BR, D), lambda r: (r, 0)),
-            pl.BlockSpec((BR, D), lambda r: (r, 0)),
+            pl.BlockSpec((br, D), lambda r: (r, 0)),
+            pl.BlockSpec((br, D), lambda r: (r, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((Rp, D), res.dtype),
             jax.ShapeDtypeStruct((Rp, D), res.dtype),
         ],
-        interpret=interpret_mode(),
+        name="residual_rmsnorm",
+        interpret=common.interpret_mode(),
     )(r2, x2, scale.reshape(1, D))
     return newres[:R].reshape(orig_shape), normed[:R].reshape(orig_shape)

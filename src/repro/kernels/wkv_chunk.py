@@ -19,7 +19,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.common import interpret_mode
+from repro.kernels import common
 
 
 def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sout_ref,
@@ -34,10 +34,19 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sout_ref,
     kk = k_ref[0, 0, 0].astype(jnp.float32)
     vv = v_ref[0, 0, 0].astype(jnp.float32)
     lw = lw_ref[0, 0, 0].astype(jnp.float32)  # log-decay, < 0
-    u = u_ref[0]  # (1?, N) -> (N,)
+    u = u_ref[0]  # (1, N)
     s = s_ref[...]  # (N, N)
 
-    cum = jnp.cumsum(lw, axis=0)
+    # inclusive prefix sum over the chunk as a lower-triangular ones matmul
+    # (Mosaic has no cumsum)
+    c = chunk
+    row = jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
+    cum = jax.lax.dot_general(
+        jnp.where(row >= col, 1.0, 0.0), lw, (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
     cum_excl = cum - lw
     # from-state: r_t decayed back to chunk start
     rq = r * jnp.exp(cum_excl)
@@ -45,13 +54,10 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sout_ref,
         rq, s, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
     # intra-chunk: A[t,s] = sum_i r_t[i] k_s[i] exp(cum_excl[t]-cum[s]), s<t
-    c = chunk
     diff = cum_excl[:, None, :] - cum[None, :, :]  # (t, s, N)
-    tri = (
-        jax.lax.broadcasted_iota(jnp.int32, (c, c), 0)
-        > jax.lax.broadcasted_iota(jnp.int32, (c, c), 1)
-    )
-    D = jnp.exp(jnp.where(tri[..., None], diff, -1e30))
+    strict = (jax.lax.broadcasted_iota(jnp.int32, diff.shape, 0)
+              > jax.lax.broadcasted_iota(jnp.int32, diff.shape, 1))
+    D = jnp.exp(jnp.where(strict, diff, -1e30))
     # A[t,s] = sum_i r[t,i] k[s,i] D[t,s,i] — elementwise form (Mosaic-safe)
     A = jnp.sum(r[:, None, :] * kk[None, :, :] * D, axis=-1)
     o_intra = jax.lax.dot_general(
@@ -59,9 +65,18 @@ def _kernel(r_ref, k_ref, v_ref, lw_ref, u_ref, s0_ref, o_ref, sout_ref,
     )
     bonus = jnp.sum(r * u * kk, axis=-1, keepdims=True)
     o_ref[0, 0, 0] = (o_state + o_intra + bonus * vv).astype(o_ref.dtype)
-    # state update: decay everything to chunk end
-    dec_end = jnp.exp(cum[-1][None, :] - cum)  # (c, N)
-    s_new = jnp.exp(cum[-1])[:, None] * s + jax.lax.dot_general(
+    # state update: decay everything to chunk end; the per-key-row decay of
+    # the carried state is a diagonal matmul (no lane->sublane transpose)
+    last = cum[c - 1:c, :]  # (1, N)
+    dec_end = jnp.exp(last - cum)  # (c, N)
+    n = s.shape[0]
+    eye = (jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
+           == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1))
+    decay = jnp.where(eye, jnp.exp(last), 0.0)
+    s_new = jax.lax.dot_general(
+        decay, s, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    ) + jax.lax.dot_general(
         kk * dec_end, vv, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
@@ -91,7 +106,7 @@ def wkv_chunk(r, k, v, lw, u, s0, chunk=64):
             pl.BlockSpec((1, 1, 1, chunk, N), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, N), lambda b, h, c: (b, h, c, 0, 0)),
             pl.BlockSpec((1, 1, 1, chunk, N), lambda b, h, c: (b, h, c, 0, 0)),
-            pl.BlockSpec((1, N), lambda b, h, c: (h, 0)),
+            pl.BlockSpec((1, 1, N), lambda b, h, c: (h, 0, 0)),
             pl.BlockSpec((1, 1, N, N), lambda b, h, c: (b, h, 0, 0)),
         ],
         out_specs=[
@@ -103,7 +118,8 @@ def wkv_chunk(r, k, v, lw, u, s0, chunk=64):
             jax.ShapeDtypeStruct((B, H, N, N), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((N, N), jnp.float32)],
-        interpret=interpret_mode(),
-    )(rb, kb, vb, lwb, u, s0)
+        name="wkv_chunk",
+        interpret=common.interpret_mode(),
+    )(rb, kb, vb, lwb, u.reshape(H, 1, N), s0)
     out = out.reshape(B, H, S, N).transpose(0, 2, 1, 3)
     return out, s_final

@@ -9,12 +9,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # jax < 0.5 has no sharding.AxisType; Auto is the default there anyway
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    kwargs = (
-        {"axis_types": (axis_type.Auto,) * len(axes)} if axis_type else {}
-    )
-    return jax.make_mesh(shape, axes, **kwargs)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

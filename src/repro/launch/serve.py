@@ -41,6 +41,7 @@ import numpy as np
 
 from repro.configs import get_arch, list_archs, smoke_variant
 from repro.configs.base import RunConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.runtime.server import Request, ServeEngine
 
@@ -206,39 +207,47 @@ def serve_cnn_supervised(args, prog, in_shape) -> None:
     print(asyncio.run(main()), end="")
 
 
-def serve_cnn(args) -> None:
-    from repro import marvel
-    from repro.models.cnn import get_cnn
+async def serve_cnn_wave(prog, in_shape, images, *, max_batch: int,
+                         max_delay_ms: float) -> tuple[list, dict]:
+    """Warm every batch bucket, then serve ``images`` as one wave through
+    the async engine.  Returns ``(results, metrics)``; the metrics add
+    ``warmup_s``, ``wave_s`` and ``recompiles_after_warmup`` to the
+    engine's own."""
+    engine = prog.serve(mode="async", max_batch=max_batch,
+                        max_delay_ms=max_delay_ms)
+    async with engine:
+        t0 = time.perf_counter()
+        engine.warmup(in_shape)
+        warmup_s = time.perf_counter() - t0
+        misses = prog.cache_misses
+        t0 = time.perf_counter()
+        results = await engine.submit_wave(images)
+        wave_s = time.perf_counter() - t0
+        m = engine.metrics()
+    m.update(warmup_s=warmup_s, wave_s=wave_s,
+             recompiles_after_warmup=prog.cache_misses - misses)
+    return results, m
 
-    init, apply, in_shape = get_cnn(args.cnn)
+
+def serve_cnn(args) -> None:
+    from repro.models.cnn import get_cnn
+    from repro.runtime.actor import cnn_program_factory
+
+    in_shape = get_cnn(args.cnn)[2]
     if args.supervised and args.isolation == "process":
         serve_cnn_supervised(args, None, in_shape)
         return
-    params = init(jax.random.PRNGKey(0))
-    x = np.zeros((1, *in_shape), np.float32)
-    prog = marvel.compile(apply, x, params=params, level="v4",
-                          precompile=False).shard()  # all local devices (DP)
+    prog = cnn_program_factory(args.cnn)
     if args.supervised:
         serve_cnn_supervised(args, prog, in_shape)
         return
-    engine = prog.serve(mode="async", max_batch=args.max_batch,
-                        max_delay_ms=args.max_delay_ms)
-
-    async def main() -> dict:
-        async with engine:
-            engine.warmup(in_shape)
-            t0 = time.perf_counter()
-            results = await engine.submit_wave(
-                random_images(in_shape, args.requests)
-            )
-            dt = time.perf_counter() - t0
-            print(f"served {len(results)} requests in "
-                  f"{engine.batches_run} batches over {prog.dp_shards} "
-                  f"DP shard(s) in {dt * 1e3:.1f} ms "
-                  f"({dt / args.requests * 1e6:.0f} us/request)")
-            return engine.metrics()
-
-    print(json.dumps(asyncio.run(main()), indent=1))
+    results, m = asyncio.run(serve_cnn_wave(
+        prog, in_shape, random_images(in_shape, args.requests),
+        max_batch=args.max_batch, max_delay_ms=args.max_delay_ms))
+    print(f"served {len(results)} requests in {m['batches']} batches over "
+          f"{prog.dp_shards} DP shard(s) in {m['wave_s'] * 1e3:.1f} ms "
+          f"({m['wave_s'] / args.requests * 1e6:.0f} us/request)")
+    print(json.dumps(m, indent=1))
 
 
 def main(argv=None):
@@ -270,6 +279,7 @@ def main(argv=None):
                          "(default) or one OS process per worker with its "
                          "own device slice (crash-only recovery)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     if args.supervised and not (args.cnn or args.lm):
         ap.error("--supervised requires --cnn or --lm")
     if args.isolation == "process" and not args.supervised:

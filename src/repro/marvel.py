@@ -168,9 +168,12 @@ class MarvelProgram:
 
         Every bucket executable is subsequently AOT-compiled against
         ``NamedSharding`` inputs — batch axis split over the mesh's batch
-        axes (``pod``/``data``), everything else replicated — so one program
+        axes (``pod``/``data``), everything else replicated — and runs once
+        per device on its batch shard (see :meth:`lower`), so one program
         serves all the mesh's chips and the engines above it need no
-        per-shard logic.  Pass a ``make_production_mesh()``, any caller
+        per-shard logic.  Anything the program computes across the batch
+        (the per-tensor activation scale of the int8 kernels) is then
+        computed per shard.  Pass a ``make_production_mesh()``, any caller
         mesh, or nothing (a 1-D DP mesh over every local device).
 
         ``rules`` overrides the input-placement rule: a callable
@@ -213,11 +216,40 @@ class MarvelProgram:
         """AOT-lower for these args (ShapeDtypeStructs fine); no caching.
 
         When sharded, lowering pins the batch-DP ``NamedSharding`` on every
-        input, so the compiled executable runs SPMD across the mesh."""
+        input and runs the program once per device on its batch shard
+        (:func:`jax.shard_map`), so the compiled executable runs SPMD across
+        the mesh.  Per-shard is what makes it compile: Mosaic kernels cannot
+        be partitioned automatically."""
         shardings = self._in_shardings(args)
-        jit_kwargs = {} if shardings is None else {"in_shardings": shardings}
-        return jax.jit(self._executable_fn(*args), donate_argnums=self.donate,
-                       **jit_kwargs).lower(*args)
+        if shardings is None:
+            return jax.jit(self._executable_fn(*args),
+                           donate_argnums=self.donate).lower(*args)
+        from jax.sharding import PartitionSpec as P
+
+        from repro.launch.mesh import batch_axes
+
+        def is_split(s):
+            return len(s.spec) > 0 and s.spec[0] is not None
+
+        def shard_aval(a, s):
+            shape = tuple(a.shape)
+            if is_split(s):
+                shape = (shape[0] // self.dp_shards, *shape[1:])
+            return jax.ShapeDtypeStruct(shape, a.dtype)
+
+        # the per-device program is traced (and rewritten) at shard shapes
+        fn = self._executable_fn(
+            *jax.tree_util.tree_map(shard_aval, args, shardings))
+        specs = jax.tree_util.tree_map(lambda s: s.spec, shardings)
+        split = any(is_split(s) for s in jax.tree_util.tree_leaves(shardings))
+        # outputs keep the batch split when any input carried it (an
+        # undivided batch runs replicated on every device)
+        per_shard = jax.shard_map(
+            fn, mesh=self.mesh, in_specs=specs,
+            out_specs=P(batch_axes(self.mesh)) if split else P(),
+            check_vma=False)
+        return jax.jit(per_shard, donate_argnums=self.donate,
+                       in_shardings=shardings).lower(*args)
 
     def executable_for(self, *args):
         """The compiled executable for this shape/dtype bucket (build on

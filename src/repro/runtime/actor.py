@@ -17,9 +17,10 @@ Topology and protocol::
                                                   │ length-prefixed frames
     child process:  _child_main ── program.serve()┘ (repro.runtime.rpc)
 
-* The **child** applies its :class:`DeviceAllocation` (pins
-  ``jax_default_device`` to its assigned device slice and shards over a
-  private mesh when given several — closing the shared-mesh gap), rebuilds
+* The **child** applies its :class:`DeviceAllocation` (on TPU, confines
+  libtpu to its chip before JAX starts; pins ``jax_default_device`` to its
+  assigned device slice and shards over a private mesh when given several
+  — closing the shared-mesh gap), rebuilds
   its ``MarvelProgram`` from a picklable *factory* reference (programs
   hold traced executables and never cross the pipe), starts its engine,
   warms the recorded AOT specs, then HELLOs.  From then on it serves
@@ -46,8 +47,10 @@ reopens and reports ``recompiles_after_warmup=0``.
 from __future__ import annotations
 
 import asyncio
+import glob
 import multiprocessing
 import os
+import re
 import signal
 import tempfile
 import time
@@ -67,10 +70,45 @@ class DeviceAllocation:
     """One actor's device grant: local device *indices* (into
     ``jax.devices(platform)``) plus the platform they index into.  The
     child pins ``jax_default_device`` to its first grant and shards over a
-    private 1-D mesh when granted several devices."""
+    private 1-D mesh when granted several devices.
+
+    ``confine`` (one-chip TPU grants): the child exports :meth:`env`
+    before JAX starts, so libtpu opens only the granted chip and every
+    device the child sees is its grant — a chip belongs to one process."""
 
     indices: tuple[int, ...] = (0,)
     platform: str | None = None
+    confine: bool = False
+
+    def env(self) -> dict[str, str]:
+        """libtpu's visible-chip variables for a confined grant ({} when
+        the grant is not confined)."""
+        if not self.confine:
+            return {}
+        return {
+            "TPU_VISIBLE_CHIPS": ",".join(str(i) for i in self.indices),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+        }
+
+
+def local_devices() -> tuple[str, int]:
+    """``(platform, device count)`` of this host, read without starting a
+    JAX backend: a parent that starts one holds the TPU chips its worker
+    processes need.  TPU chips are counted from their device files
+    (``/dev/accel*``, or the numbered ``/dev/vfio`` groups); otherwise the
+    host's CPU devices (``--xla_force_host_platform_device_count``)."""
+    wanted = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
+    if wanted in ("", "tpu"):
+        chips = (glob.glob("/dev/accel[0-9]*")
+                 or glob.glob("/dev/vfio/[0-9]*"))
+        if chips:
+            return "tpu", len(chips)
+        if wanted == "tpu":
+            raise RuntimeError("JAX_PLATFORMS=tpu but no TPU chip is visible")
+    m = re.search(r"--xla_force_host_platform_device_count=(\d+)",
+                  os.environ.get("XLA_FLAGS", ""))
+    return "cpu", int(m.group(1)) if m else 1
 
 
 def allocation_plan(workers: int, n_devices: int | None = None,
@@ -79,20 +117,34 @@ def allocation_plan(workers: int, n_devices: int | None = None,
 
     With devices to spare, each worker gets a contiguous slice (remainder
     devices go to the lowest-indexed workers); with more workers than
-    devices, workers share round-robin — one device each, oversubscribed.
-    Deterministic in ``index``, so a replacement actor always inherits the
-    dead one's exact slice.
+    devices, CPU workers share round-robin — one device each,
+    oversubscribed.  A TPU chip belongs to one process, so on TPU more
+    workers than chips raises, and a worker takes either one chip
+    (confined to it) or, alone, the whole host.  Deterministic in
+    ``index``, so a replacement actor always inherits the dead one's exact
+    slice.  The defaults come from :func:`local_devices`; no JAX backend is
+    started here.
     """
     if workers < 1:
         raise ValueError(f"workers must be >= 1, got {workers}")
     if n_devices is None or platform is None:
-        import jax
-        if n_devices is None:
-            n_devices = len(jax.devices())
-        if platform is None:
-            platform = jax.default_backend()
+        found_platform, found_n = local_devices()
+        platform = platform or found_platform
+        n_devices = found_n if n_devices is None else n_devices
     if n_devices < 1:
         raise ValueError(f"n_devices must be >= 1, got {n_devices}")
+    if platform == "tpu" and workers > 1:
+        if workers > n_devices:
+            raise ValueError(
+                f"{workers} workers need {workers} TPU chips but the host "
+                f"has {n_devices}: a chip belongs to one process")
+        if workers != n_devices:
+            raise ValueError(
+                f"{workers} workers on {n_devices} TPU chips: TPU workers "
+                f"take one chip each (workers == chips) or the whole host "
+                f"(one worker)")
+        return [DeviceAllocation((i,), platform, confine=True)
+                for i in range(workers)]
     if n_devices < workers:
         return [DeviceAllocation((i % n_devices,), platform)
                 for i in range(workers)]
@@ -107,14 +159,23 @@ def allocation_plan(workers: int, n_devices: int | None = None,
 
 
 def _apply_allocation(alloc: DeviceAllocation | None) -> list:
-    """Child-side: pin this process to its granted devices; returns them."""
+    """Child-side: pin this process to its granted devices; returns them.
+    A confined grant's chips are all this process can see (its
+    :meth:`DeviceAllocation.env` was exported before JAX started)."""
     import jax
     if alloc is None:
         return []
     if alloc.platform is not None:
         jax.config.update("jax_platform_name", alloc.platform)
     devices = jax.devices(alloc.platform)
-    granted = [devices[i] for i in alloc.indices if i < len(devices)]
+    if alloc.confine:
+        granted = list(devices)
+        if len(granted) != len(alloc.indices):
+            raise RuntimeError(
+                f"allocation {alloc} is confined to {len(alloc.indices)} "
+                f"chip(s) but the process sees {len(granted)}")
+    else:
+        granted = [devices[i] for i in alloc.indices if i < len(devices)]
     if not granted:
         raise RuntimeError(
             f"allocation {alloc} grants no device (only {len(devices)} "
@@ -128,17 +189,22 @@ def _apply_allocation(alloc: DeviceAllocation | None) -> list:
 
 
 def cnn_program_factory(model: str = "lenet5", level: str = "v4",
-                        seed: int = 0, shard: bool = True):
-    """Rebuild a compiled CNN program inside the actor process."""
+                        seed: int = 0, shard: bool = True,
+                        in_shape: tuple[int, ...] | None = None):
+    """The CNN serving artifact — seeded weights, ``marvel.compile`` at
+    ``level`` (buckets compile on demand), sharded data-parallel over the
+    local devices when there are several.  Built inside each actor
+    process, and by the in-process launcher.  ``in_shape`` defaults to
+    the model's registered input."""
     import jax
     import numpy as np
 
     from repro import marvel
     from repro.models.cnn import get_cnn
 
-    init, apply, in_shape = get_cnn(model)
+    init, apply, native_shape = get_cnn(model)
     params = init(jax.random.PRNGKey(seed))
-    x = np.zeros((1, *in_shape), np.float32)
+    x = np.zeros((1, *(in_shape or native_shape)), np.float32)
     prog = marvel.compile(apply, x, params=params, level=level,
                           precompile=False)
     if shard and len(jax.devices()) > 1:
@@ -208,6 +274,11 @@ def child_entry(spec: ActorSpec, sock_path: str) -> None:
     slow = getattr(spec.fault_plan, "slow_start_ms", 0.0) or 0.0
     if slow:
         time.sleep(slow / 1e3)
+    if spec.allocation is not None:
+        os.environ.update(spec.allocation.env())  # before JAX starts
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     asyncio.run(_child_main(spec, sock_path))
 
 

@@ -59,6 +59,7 @@ class CnnRequest:
     image: np.ndarray  # (H, W, C), model input layout
     label: int | None = None
     probs: np.ndarray | None = None
+    logits: np.ndarray | None = None  # the program's output row
     done: bool = False  # resolved successfully (failed requests set .error)
     latency_ms: float = 0.0
     error: Exception | None = None
@@ -98,9 +99,9 @@ class _BucketedCompute:
             self.warmed.append(spec)
 
     def classify(self, images: list[np.ndarray], uids: tuple[int, ...] = ()
-                 ) -> tuple[np.ndarray, np.ndarray]:
-        """One padded bucket through the program -> (labels, probs) for the
-        real lanes (padding lanes are computed and discarded)."""
+                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """One padded bucket through the program -> (labels, probs, logits)
+        for the real lanes (padding lanes are computed and discarded)."""
         if self.faults is not None:
             self.faults.before_compute(uids)
         n = len(images)
@@ -109,7 +110,7 @@ class _BucketedCompute:
         logits = np.asarray(self.program(x))[:n]
         z = logits - logits.max(axis=-1, keepdims=True)
         probs = np.exp(z) / np.exp(z).sum(axis=-1, keepdims=True)
-        return np.argmax(logits, axis=-1), probs
+        return np.argmax(logits, axis=-1), probs, logits
 
 
 def _classify_resilient(compute: _BucketedCompute, reqs: list[CnnRequest],
@@ -118,7 +119,8 @@ def _classify_resilient(compute: _BucketedCompute, reqs: list[CnnRequest],
     """The resilient compute path (runs on the compute thread).
 
     Returns ``(outcomes, retries)`` where ``outcomes[i]`` is
-    ``("ok", label, probs)`` or ``("err", exception)`` for ``reqs[i]``.
+    ``("ok", label, probs, logits)`` or ``("err", exception)`` for
+    ``reqs[i]``.
     Failed attempts retry with backoff; a still-failing multi-request batch
     bisects (within ``retry.max_splits``) to isolate the poison pill; a
     singleton — or a sub-batch whose split budget ran out — fails
@@ -133,10 +135,10 @@ def _classify_resilient(compute: _BucketedCompute, reqs: list[CnnRequest],
         err: Exception | None = None
         for attempt in range(retry.max_retries + 1):
             try:
-                labels, probs = compute.classify(
+                labels, probs, logits = compute.classify(
                     [r.image for r in sub], uids=tuple(r.uid for r in sub)
                 )
-                return [("ok", int(labels[i]), probs[i])
+                return [("ok", int(labels[i]), probs[i], logits[i])
                         for i in range(len(sub))]
             except faults.WorkerDeath:
                 raise
@@ -223,6 +225,7 @@ class CnnBatchEngine:
             else:
                 req.label = out[1]
                 req.probs = out[2]
+                req.logits = out[3]
                 req.done = True
                 self._metrics.completed += 1
                 self._metrics.observe_latency(ms)
@@ -558,6 +561,7 @@ class AsyncCnnEngine:
                 continue
             req.label = out[1]
             req.probs = out[2]
+            req.logits = out[3]
             req.done = True
             self._metrics.completed += 1
             self._metrics.observe_latency(req.latency_ms)

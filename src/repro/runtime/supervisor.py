@@ -49,7 +49,9 @@ Process isolation
 worker in its own OS process (:class:`~repro.runtime.actor.WorkerActor`):
 the engine lives child-side behind a length-prefixed RPC channel, each
 actor pins its own device slice from a deterministic
-:func:`~repro.runtime.actor.allocation_plan`, and crash detection rides
+:func:`~repro.runtime.actor.allocation_plan` (planned without starting a
+JAX backend in this process, so the children can open the chips; on TPU
+one chip per worker, never shared), and crash detection rides
 the process *sentinel* — a SIGKILLed worker fails its in-flight requests
 into the same failover path the in-process tier uses, and the warm-handoff
 respawn (replay recorded warmup specs, then reopen routing) is identical.

@@ -119,6 +119,50 @@ class TestAllocationPlan:
         with pytest.raises(ValueError, match="n_devices"):
             allocation_plan(2, n_devices=0, platform="cpu")
 
+    def test_tpu_gives_each_worker_its_own_confined_chip(self):
+        plan = allocation_plan(4, n_devices=4, platform="tpu")
+        assert [a.indices for a in plan] == [(0,), (1,), (2,), (3,)]
+        assert all(a.confine for a in plan)
+        assert plan[2].env() == {"TPU_VISIBLE_CHIPS": "2",
+                                 "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+                                 "TPU_PROCESS_BOUNDS": "1,1,1"}
+        # one worker takes the whole host and sees every chip
+        (whole,) = allocation_plan(1, n_devices=4, platform="tpu")
+        assert whole.indices == (0, 1, 2, 3) and whole.env() == {}
+
+    @pytest.mark.parametrize("workers,chips", [(2, 1), (5, 4), (2, 4)])
+    def test_tpu_never_shares_or_splits_chips_unevenly(self, workers, chips):
+        with pytest.raises(ValueError, match="TPU chip|TPU workers"):
+            allocation_plan(workers, n_devices=chips, platform="tpu")
+
+    def test_local_devices_reads_the_host_without_jax(self, monkeypatch):
+        from repro.runtime.actor import local_devices
+
+        monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+        monkeypatch.setenv("XLA_FLAGS",
+                           "--xla_force_host_platform_device_count=3")
+        assert local_devices() == ("cpu", 3)
+        monkeypatch.delenv("XLA_FLAGS")
+        assert local_devices() == ("cpu", 1)
+
+    def test_parent_side_planning_starts_no_jax_backend(self):
+        """The supervisor plans worker devices in the parent; starting a
+        backend there would take the chips its workers need."""
+        import subprocess
+        import sys
+
+        code = (
+            "from repro.runtime import supervisor\n"
+            "from repro.runtime.actor import allocation_plan\n"
+            "plan = allocation_plan(2)\n"
+            "from jax._src import xla_bridge\n"
+            "assert not xla_bridge.backends_are_initialized(), plan\n"
+            "print(len(plan))\n"
+        )
+        out = subprocess.run([sys.executable, "-c", code], check=True,
+                             capture_output=True, text=True, timeout=120)
+        assert out.stdout.strip() == "2"
+
 
 # ---------------------------------------------------------------------------
 # spec + plan picklability (the spawn boundary is a pickle hop)
