@@ -1,0 +1,6 @@
+"""The fused_conv kernel's share of its roofline (see chipbench.roofline)."""
+from chipbench.roofline import kernel_roofline
+
+
+def read(ctx):
+    return kernel_roofline(ctx, "fused_conv")

@@ -1,0 +1,5 @@
+"""Images answered inside the window, per second of the window."""
+
+
+def read(ctx):
+    return len(ctx.answered_in_window()) / ctx.seconds
