@@ -102,6 +102,7 @@ class MarvelProgram:
     rewrite_baked: bool = False
     cache_hits: int = 0
     cache_misses: int = 0
+    build_s: float = 0.0  # seconds lowering + compiling on cache misses
     mesh: Any = None  # set by shard(); executables compile against it
     # the bound (possibly fake-quantized) parameter pytree, kept so
     # serve(mode="lm") can build decode engines without re-threading params
@@ -259,7 +260,9 @@ class MarvelProgram:
         exe = self._cache.get(key)
         if exe is None:
             self.cache_misses += 1
+            t0 = time.perf_counter()
             exe = self.lower(*args).compile()
+            self.build_s += time.perf_counter() - t0
             self._cache[key] = exe
         else:
             self.cache_hits += 1
@@ -343,12 +346,13 @@ class MarvelProgram:
         return engines[mode](self, **engine_kwargs)
 
     def metrics(self) -> dict:
-        """Cache + shard counters, the program's slice of the serving
+        """Cache, build and shard counters, the program's slice of the serving
         metrics surface (the engines merge this into theirs)."""
         return {
             "cache_hits": self.cache_hits,
             "cache_misses": self.cache_misses,
             "cache_size": self.cache_size,
+            "build_s": self.build_s,
             "dp_shards": self.dp_shards,
         }
 

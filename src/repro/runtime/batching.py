@@ -243,6 +243,19 @@ class EngineMetrics:
     # in batch, so this stays == batches (one handoff per flush), never
     # == completed (one per request) — asserted by tests and bench_serving
     loop_handoffs: int = 0
+    # cumulative seconds by phase, the same boundaries as the
+    # ``marvel.serve.*`` spans: the compute thread's stack (+pad), dispatch,
+    # result wait (device run + device-to-host copy) and post-processing
+    # (CNN engines), each request's wait from admission to its batch's
+    # dispatch, and each batch's wait for the compute thread (async
+    # engine).  Each is written by one thread only: the compute thread, or
+    # the event loop for queue_wait_s
+    stack_s: float = 0.0
+    dispatch_s: float = 0.0
+    result_wait_s: float = 0.0
+    post_s: float = 0.0
+    queue_wait_s: float = 0.0
+    executor_wait_s: float = 0.0
     _latencies_ms: Reservoir = field(default_factory=Reservoir)
 
     def observe_latency(self, ms: float) -> None:
@@ -278,6 +291,12 @@ class EngineMetrics:
             "shed": self.shed,
             "deadline_failures": self.deadline_failures,
             "restarts": self.restarts,
+            "stack_s": self.stack_s,
+            "dispatch_s": self.dispatch_s,
+            "result_wait_s": self.result_wait_s,
+            "post_s": self.post_s,
+            "queue_wait_s": self.queue_wait_s,
+            "executor_wait_s": self.executor_wait_s,
             "p50_latency_ms": self.latency_ms(50),
             "p99_latency_ms": self.latency_ms(99),
         }

@@ -36,21 +36,45 @@ sheds load at admission with a ``retry_after_ms`` hint on
 :class:`~repro.runtime.faults.FaultInjector` passed as ``faults=`` drives
 all of these paths deterministically (see ``docs/serving_ops.md``); the
 supervisor tier above this module is :mod:`repro.runtime.supervisor`.
+
+Tracing
+-------
+Each phase of a batch is a ``jax.profiler.TraceAnnotation`` span, inert
+unless a profiler is running, and adds its seconds to a counter on
+``metrics()``.  On the compute thread (leaf spans, never nested):
+``marvel.serve.stack`` (``stack_s``), ``marvel.serve.dispatch``
+(``dispatch_s``), ``marvel.serve.result_wait`` (``result_wait_s``: the
+device run and the device-to-host copy), ``marvel.serve.post``
+(``post_s``) and, async engine, ``marvel.serve.handoff``; on the event
+loop ``marvel.serve.resolve``.  Every span carries ``batch``, the id the
+engine gave the batch; the compute spans also carry ``size``, ``bucket``
+and ``attempt`` (retries and bisection call the program again).  The
+async engine also counts ``queue_wait_s`` (admission to dispatch, summed
+over requests) and ``executor_wait_s`` (dispatch to the compute thread
+taking the batch, summed over batches); ``build_s`` is the program's
+seconds compiling bucket executables.
 """
 from __future__ import annotations
 
 import asyncio
 import concurrent.futures
+import operator
 import time
 from dataclasses import dataclass
 
 import jax
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.runtime import batching, faults
 from repro.runtime.batching import (  # re-exports  # noqa: F401
     AdmissionError, DeadlineExceeded, RetryPolicy, WorkerUnavailable,
 )
+
+
+# a queued item is (request, future, admission time on the loop's clock,
+# deadline or None)
+_ADMITTED = operator.itemgetter(2)
 
 
 @dataclass
@@ -72,7 +96,8 @@ class _BucketedCompute:
 
     def __init__(self, program, max_batch: int = 8,
                  buckets: tuple[int, ...] = (),
-                 faults_injector: faults.FaultInjector | None = None):
+                 faults_injector: faults.FaultInjector | None = None,
+                 metrics: batching.EngineMetrics | None = None):
         self.program = program
         if not buckets:
             buckets = batching.pow2_buckets(max_batch)
@@ -80,6 +105,12 @@ class _BucketedCompute:
         self.buckets = batching.round_up_buckets(buckets, dp)
         self.max_batch = self.buckets[-1]
         self.faults = faults_injector
+        # the engine's counters; classify adds each phase's seconds
+        self.metrics = metrics if metrics is not None else \
+            batching.EngineMetrics()
+        # tags of the classify call in progress, set by the compute path:
+        # the engine's batch id and the call's number within the batch
+        self.tags = {"batch": 0, "attempt": 0}
         # every warmed (shape, dtype) spec, recorded so a supervisor can
         # replay the warmup on a replacement worker before routing traffic
         self.warmed: list[tuple[tuple[int, ...], str]] = []
@@ -101,20 +132,39 @@ class _BucketedCompute:
     def classify(self, images: list[np.ndarray], uids: tuple[int, ...] = ()
                  ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
         """One padded bucket through the program -> (labels, probs, logits)
-        for the real lanes (padding lanes are computed and discarded)."""
+        for the real lanes (padding lanes are computed and discarded).
+
+        Each phase is a span tagged with :attr:`tags`, ``size`` and
+        ``bucket``, and adds its seconds to :attr:`metrics`."""
         if self.faults is not None:
             self.faults.before_compute(uids)
         n = len(images)
         bucket = batching.bucket_for(self.buckets, n)
-        x = batching.pad_batch(np.stack(images), bucket)
-        logits = np.asarray(self.program(x))[:n]
-        z = logits - logits.max(axis=-1, keepdims=True)
-        probs = np.exp(z) / np.exp(z).sum(axis=-1, keepdims=True)
-        return np.argmax(logits, axis=-1), probs, logits
+        ids = dict(self.tags, size=n, bucket=bucket)
+        m = self.metrics
+        t0 = time.perf_counter()
+        with TraceAnnotation("marvel.serve.stack", **ids):
+            x = batching.pad_batch(np.stack(images), bucket)
+        t1 = time.perf_counter()
+        m.stack_s += t1 - t0
+        with TraceAnnotation("marvel.serve.dispatch", **ids):
+            out = self.program(x)
+        t2 = time.perf_counter()
+        m.dispatch_s += t2 - t1
+        with TraceAnnotation("marvel.serve.result_wait", **ids):
+            logits = np.asarray(out)[:n]
+        t3 = time.perf_counter()
+        m.result_wait_s += t3 - t2
+        with TraceAnnotation("marvel.serve.post", **ids):
+            z = logits - logits.max(axis=-1, keepdims=True)
+            probs = np.exp(z) / np.exp(z).sum(axis=-1, keepdims=True)
+            labels = np.argmax(logits, axis=-1)
+        m.post_s += time.perf_counter() - t3
+        return labels, probs, logits
 
 
 def _classify_resilient(compute: _BucketedCompute, reqs: list[CnnRequest],
-                        retry: batching.RetryPolicy
+                        retry: batching.RetryPolicy, batch: int = 0
                         ) -> tuple[list[tuple], int]:
     """The resilient compute path (runs on the compute thread).
 
@@ -126,14 +176,17 @@ def _classify_resilient(compute: _BucketedCompute, reqs: list[CnnRequest],
     singleton — or a sub-batch whose split budget ran out — fails
     per-request.  :class:`~repro.runtime.faults.WorkerDeath` is NOT handled
     here: the worker is dying, not the batch, so it propagates to the
-    engine's fatal path.
+    engine's fatal path.  ``batch``, the engine's id for the batch, and
+    the call's number within it tag every call's spans.
     """
-    retries = 0
+    retries, calls = 0, 0
 
     def solve(sub: list[CnnRequest], splits_left: int | None) -> list[tuple]:
-        nonlocal retries
+        nonlocal retries, calls
         err: Exception | None = None
         for attempt in range(retry.max_retries + 1):
+            compute.tags = {"batch": batch, "attempt": calls}
+            calls += 1
             try:
                 labels, probs, logits = compute.classify(
                     [r.image for r in sub], uids=tuple(r.uid for r in sub)
@@ -165,12 +218,13 @@ class CnnBatchEngine:
                  max_pending: int | None = None,
                  faults: faults.FaultInjector | None = None,
                  retry: batching.RetryPolicy | None = None):
+        self._metrics = batching.EngineMetrics()
         self.compute = _BucketedCompute(program, max_batch, buckets,
-                                        faults_injector=faults)
+                                        faults_injector=faults,
+                                        metrics=self._metrics)
         self.retry = retry or batching.RetryPolicy()
         self.queue = batching.BoundedQueue(capacity=max_pending)
         self.results: dict[int, CnnRequest] = {}
-        self._metrics = batching.EngineMetrics()
 
     @property
     def program(self):
@@ -211,8 +265,8 @@ class CnnBatchEngine:
             return []
         t0 = time.perf_counter()
         reqs = self.queue.pop_up_to(self.max_batch)
-        outcomes, retries = _classify_resilient(self.compute, reqs,
-                                                self.retry)
+        outcomes, retries = _classify_resilient(
+            self.compute, reqs, self.retry, batch=self._metrics.batches)
         self._metrics.retries += retries
         bucket = batching.bucket_for(self.buckets, len(reqs))
         self._metrics.observe_batch(len(reqs), bucket)
@@ -282,12 +336,14 @@ class AsyncCnnEngine:
                  max_delay_ms: float = 2.0,
                  faults: faults.FaultInjector | None = None,
                  retry: batching.RetryPolicy | None = None):
+        self._metrics = batching.EngineMetrics()
         self.compute = _BucketedCompute(program, max_batch, buckets,
-                                        faults_injector=faults)
+                                        faults_injector=faults,
+                                        metrics=self._metrics)
         self.retry = retry or batching.RetryPolicy()
         self.max_pending = max_pending
         self.max_delay_ms = max_delay_ms
-        self._metrics = batching.EngineMetrics()
+        self._dispatched = 0  # batches handed to the compute thread: the id
         self._queue: asyncio.Queue | None = None
         self._batcher: asyncio.Task | None = None
         self._pool: concurrent.futures.ThreadPoolExecutor | None = None
@@ -375,8 +431,11 @@ class AsyncCnnEngine:
 
     def _retry_after_hint_ms(self) -> float:
         """Load-shedding hint: estimated drain time of the current backlog
-        (batches ahead x observed per-batch latency)."""
-        per_batch = self._metrics.latency_ms(50) or self.max_delay_ms
+        (batches ahead x the compute thread's mean seconds per batch, or
+        ``max_delay_ms`` before the first batch)."""
+        m = self._metrics
+        busy_s = m.stack_s + m.dispatch_s + m.result_wait_s + m.post_s
+        per_batch = 1e3 * busy_s / m.batches if m.batches else self.max_delay_ms
         backlog = -(-max(self._live_reqs, 1) // self.compute.max_batch)
         return per_batch * backlog
 
@@ -488,43 +547,62 @@ class AsyncCnnEngine:
                 deadline_flush = False  # bucket filled before the deadline
             self._dispatch(loop, batch, deadline_flush)
         # the sentinel only stops coalescing; every dispatched batch must
-        # still resolve before stop() returns
+        # still resolve before stop() returns.  Drop what was awaited here:
+        # a future already done is awaited without yielding to the loop, so
+        # its queued discard callback would never run and this would spin
         while self._inflight:
-            await asyncio.gather(*list(self._inflight))
+            inflight = list(self._inflight)
+            await asyncio.gather(*inflight)
+            self._inflight.difference_update(inflight)
 
     def _dispatch(self, loop, batch, deadline_flush: bool) -> None:
         """Hand one coalesced batch to the compute thread and return
         immediately (the batcher keeps coalescing while compute runs)."""
         reqs = [b[0] for b in batch]
+        batch_id = self._dispatched
+        self._dispatched += 1
+        # summed over the batch: now less each request's admission time
+        # (both on the loop's clock)
+        self._metrics.queue_wait_s += (len(batch) * loop.time()
+                                       - sum(map(_ADMITTED, batch)))
+        t_dispatch = time.perf_counter()
 
         def compute_then_resolve():
             # compute thread: the resilient blocking jax dispatch
             # (retry/backoff + bisection), then ONE call_soon_threadsafe
             # hands the finished batch to the loop
+            self._metrics.executor_wait_s += time.perf_counter() - t_dispatch
             retries = 0
             try:
                 outcomes, retries = _classify_resilient(
-                    self.compute, reqs, self.retry
+                    self.compute, reqs, self.retry, batch=batch_id
                 )
                 err = None
             except Exception as e:  # WorkerDeath or a catastrophic failure
                 outcomes, err = None, e
-            try:
-                loop.call_soon_threadsafe(
-                    self._resolve_batch, loop, batch, outcomes, retries, err,
-                    deadline_flush,
-                )
-            except RuntimeError:
-                pass  # loop closed during worker death; futures already dead
+            with TraceAnnotation("marvel.serve.handoff", batch=batch_id):
+                try:
+                    loop.call_soon_threadsafe(
+                        self._resolve_batch, loop, batch_id, batch, outcomes,
+                        retries, err, deadline_flush,
+                    )
+                except RuntimeError:
+                    pass  # loop closed during worker death; futures already dead
 
         fut = loop.run_in_executor(self._pool, compute_then_resolve)
         self._inflight.add(fut)
         fut.add_done_callback(self._inflight.discard)
 
-    def _resolve_batch(self, loop, batch, outcomes, retries, err,
+    def _resolve_batch(self, loop, batch_id, batch, outcomes, retries, err,
                        deadline_flush: bool) -> None:
         """Event-loop callback: resolve a whole batch's futures (submission
-        order within the batch) and record its metrics."""
+        order within the batch) and record its metrics, inside the
+        ``marvel.serve.resolve`` span."""
+        with TraceAnnotation("marvel.serve.resolve", batch=batch_id):
+            self._resolve(loop, batch, outcomes, retries, err, deadline_flush)
+
+    def _resolve(self, loop, batch, outcomes, retries, err,
+                 deadline_flush: bool) -> None:
         if self._killed is not None:
             return  # kill() already failed the futures; don't double-count
         self._live_reqs -= len(batch)
@@ -599,10 +677,12 @@ class AsyncCnnEngine:
 
 
 def _program_metrics(program) -> dict:
-    """Cache hit/miss + shard counters re-exported from the MarvelProgram."""
+    """Cache hit/miss, build seconds and shard counters re-exported from the
+    MarvelProgram."""
     return {
         "cache_hits": getattr(program, "cache_hits", 0),
         "cache_misses": getattr(program, "cache_misses", 0),
         "cache_size": getattr(program, "cache_size", 0),
+        "build_s": getattr(program, "build_s", 0.0),
         "dp_shards": int(getattr(program, "dp_shards", 1) or 1),
     }

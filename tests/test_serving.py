@@ -8,6 +8,7 @@ warmed program never recompiles under traffic.  The multi-device DP smoke
 test only runs when ``jax.devices()`` has more than one entry.
 """
 import asyncio
+import time
 from types import SimpleNamespace
 
 import jax
@@ -302,6 +303,117 @@ def test_submit_racing_stop_is_rejected_not_dropped(lenet_prog):
         await stop_task
 
     asyncio.run(main())
+
+
+PHASES = ("stack_s", "dispatch_s", "result_wait_s", "post_s")
+
+
+def test_phase_counters_cover_every_batch(lenet_prog):
+    """Every phase counter is on ``metrics()`` and positive after a few
+    batches, and the compute thread's phases fit in the wall time."""
+    prog, _, _, in_shape = lenet_prog
+
+    async def main():
+        async with prog.serve(mode="async", max_batch=4) as engine:
+            for wave in range(3):
+                await asyncio.gather(*[
+                    engine.submit(im)
+                    for im in _images(in_shape, 4, seed=wave)
+                ])
+            return engine.metrics()
+
+    t0 = time.perf_counter()
+    m = asyncio.run(main())
+    wall = time.perf_counter() - t0
+    assert m["batches"] >= 3
+    for key in (*PHASES, "queue_wait_s", "executor_wait_s", "build_s"):
+        assert m[key] > 0, key
+    assert sum(m[k] for k in PHASES) <= wall
+
+
+def test_build_seconds_grow_on_a_bucket_miss_not_on_a_hit(lenet_prog):
+    prog, _, _, in_shape = lenet_prog
+    spec = jax.ShapeDtypeStruct((3, *in_shape), np.float32)  # no bucket's
+    misses, before = prog.cache_misses, prog.build_s
+    prog.executable_for(spec)
+    assert prog.cache_misses == misses + 1 and prog.build_s > before
+    built = prog.build_s
+    prog.executable_for(spec)
+    assert prog.build_s == built and prog.metrics()["build_s"] == built
+
+
+def test_serving_spans_in_a_profiler_trace(lenet_prog, tmp_path):
+    """Under the profiler each batch leaves every compute-thread span once,
+    joined by its batch id, and no compute-thread span encloses another."""
+    prog, _, _, in_shape = lenet_prog
+    compute = {f"marvel.serve.{p}" for p in (
+        "stack", "dispatch", "result_wait", "post", "handoff")}
+
+    async def main():
+        async with prog.serve(mode="async", max_batch=4) as engine:
+            engine.warmup(in_shape)
+            jax.profiler.start_trace(str(tmp_path))
+            try:
+                for wave in range(3):
+                    await asyncio.gather(*[
+                        engine.submit(im)
+                        for im in _images(in_shape, 4, seed=wave)
+                    ])
+            finally:
+                jax.profiler.stop_trace()
+        return engine.metrics()["batches"]
+
+    batches = asyncio.run(main())
+    (path,) = tmp_path.rglob("*.xplane.pb")
+    spans = []  # ((plane, line), name, start, end, batch)
+    for i, pl in enumerate(jax.profiler.ProfileData.from_file(str(path))
+                           .planes):
+        for j, ln in enumerate(pl.lines):
+            spans += [((i, j), e.name, e.start_ns, e.start_ns + e.duration_ns,
+                       {k: v for k, v in e.stats}["batch"])
+                      for e in ln.events if e.name.startswith("marvel.serve.")]
+    for name in (*compute, "marvel.serve.resolve"):
+        ids = sorted(b for _, n, _, _, b in spans if n == name)
+        assert ids == list(range(batches)), name
+    (line,) = {ln for ln, n, _, _, _ in spans if n in compute}
+    on_line = sorted((s, e) for ln, n, s, e, _ in spans
+                     if ln == line and n in compute)
+    assert all(e <= s2 for (_, e), (s2, _) in zip(on_line, on_line[1:]))
+
+
+def test_retry_after_hint_reads_compute_seconds_per_batch():
+    from repro.runtime.cnn_server import AsyncCnnEngine
+
+    engine = AsyncCnnEngine(SimpleNamespace(dp_shards=1), max_batch=4,
+                            max_delay_ms=3.0)
+    engine._live_reqs = 9  # three batches ahead
+    assert engine._retry_after_hint_ms() == pytest.approx(3 * 3.0)
+    m = engine._metrics
+    m.batches = 2
+    m.stack_s, m.dispatch_s, m.result_wait_s, m.post_s = (
+        0.004, 0.002, 0.012, 0.002)
+    m.observe_latency(500.0)  # queueing is no part of a batch's time
+    assert engine._retry_after_hint_ms() == pytest.approx(3 * 10.0)
+
+
+@pytest.mark.timeout(20)
+def test_stop_drains_a_batch_whose_callbacks_are_still_queued():
+    """stop() returns when an in-flight batch's future is done but the
+    callback that drops it from the in-flight set has not run yet (awaiting
+    a done future does not yield to the loop, so the drain must not rely on
+    that callback)."""
+    from repro.runtime.cnn_server import AsyncCnnEngine
+
+    async def main():
+        engine = AsyncCnnEngine(SimpleNamespace(dp_shards=1), max_batch=4)
+        await engine.start()
+        done = asyncio.get_running_loop().create_future()
+        done.set_result(None)
+        engine._inflight.add(done)  # done, its discard not yet run
+        await engine.stop()
+        return engine._inflight
+
+    assert asyncio.run(main()) == set()
 
 
 def test_serve_mode_validation(lenet_prog):
