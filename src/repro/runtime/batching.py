@@ -256,6 +256,9 @@ class EngineMetrics:
     post_s: float = 0.0
     queue_wait_s: float = 0.0
     executor_wait_s: float = 0.0
+    # batches the async engine's compute thread launched while the batch
+    # before them was still in flight (its one-batch look-ahead)
+    prefetched: int = 0
     _latencies_ms: Reservoir = field(default_factory=Reservoir)
 
     def observe_latency(self, ms: float) -> None:
@@ -297,6 +300,7 @@ class EngineMetrics:
             "post_s": self.post_s,
             "queue_wait_s": self.queue_wait_s,
             "executor_wait_s": self.executor_wait_s,
+            "prefetched": self.prefetched,
             "p50_latency_ms": self.latency_ms(50),
             "p99_latency_ms": self.latency_ms(99),
         }

@@ -53,10 +53,27 @@ async engine also counts ``queue_wait_s`` (admission to dispatch, summed
 over requests) and ``executor_wait_s`` (dispatch to the compute thread
 taking the batch, summed over batches); ``build_s`` is the program's
 seconds compiling bucket executables.
+
+Look-ahead (async engine)
+-------------------------
+When the compute thread takes up batch N and batch N+1 is already staged
+behind it, it launches N (unless already launched), then N+1, and only
+then waits on N: a launch is the stack and the program call (argument
+transfer and enqueue) plus the start of the result's copy to the host.
+N+1's stacking and input transfer so run while the device computes N,
+and N+1's executable is queued on the device behind N.  The depth is one
+batch; with no batch staged, the thread runs each batch in turn as the
+sync engine does.  The compute thread's spans then read, per batch
+taken up: ``stack`` and ``dispatch`` of N+1, then ``result_wait``,
+``post`` and ``handoff`` of N (``stack`` and ``dispatch`` of N too,
+where no look-ahead launched it), each batch leaving each span once.
+``prefetched`` on ``metrics()`` counts the batches launched while the
+batch before them was still in flight.
 """
 from __future__ import annotations
 
 import asyncio
+import collections
 import concurrent.futures
 import operator
 import time
@@ -114,6 +131,9 @@ class _BucketedCompute:
         # every warmed (shape, dtype) spec, recorded so a supervisor can
         # replay the warmup on a replacement worker before routing traffic
         self.warmed: list[tuple[tuple[int, ...], str]] = []
+        # outputs of batches launched ahead of their classify call, by the
+        # batch's uids; each is popped by the first classify for its uids
+        self.launched: dict[tuple[int, ...], object] = {}
 
     def warmup(self, in_shape: tuple[int, ...], dtype="float32") -> None:
         """Pre-compile AND prime every batch bucket: build the AOT
@@ -129,15 +149,11 @@ class _BucketedCompute:
         if spec not in self.warmed:
             self.warmed.append(spec)
 
-    def classify(self, images: list[np.ndarray], uids: tuple[int, ...] = ()
-                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
-        """One padded bucket through the program -> (labels, probs, logits)
-        for the real lanes (padding lanes are computed and discarded).
-
-        Each phase is a span tagged with :attr:`tags`, ``size`` and
-        ``bucket``, and adds its seconds to :attr:`metrics`."""
-        if self.faults is not None:
-            self.faults.before_compute(uids)
+    def _launch(self, images: list[np.ndarray]):
+        """The first half of a batch: stack + pad, then the program call
+        (argument transfer and enqueue, no wait) and the start of the
+        result's copy to the host.  Returns the program's output, a
+        ``jax.Array``."""
         n = len(images)
         bucket = batching.bucket_for(self.buckets, n)
         ids = dict(self.tags, size=n, bucket=bucket)
@@ -149,8 +165,39 @@ class _BucketedCompute:
         m.stack_s += t1 - t0
         with TraceAnnotation("marvel.serve.dispatch", **ids):
             out = self.program(x)
+            out.copy_to_host_async()
+        m.dispatch_s += time.perf_counter() - t1
+        return out
+
+    def launch(self, images: list[np.ndarray], uids: tuple[int, ...]
+               ) -> bool:
+        """Launch a batch ahead of its :meth:`classify` call, which picks
+        the output up by ``uids``.  Launches nothing and returns False
+        where a launched batch already holds these ``uids``."""
+        if uids in self.launched:
+            return False
+        self.launched[uids] = self._launch(images)
+        return True
+
+    def classify(self, images: list[np.ndarray], uids: tuple[int, ...] = ()
+                 ) -> tuple[np.ndarray, np.ndarray, np.ndarray]:
+        """One padded bucket through the program -> (labels, probs, logits)
+        for the real lanes (padding lanes are computed and discarded).
+        Picks up the batch :meth:`launch` launched for ``uids``, else
+        launches it here.
+
+        Each phase is a span tagged with :attr:`tags`, ``size`` and
+        ``bucket``, and adds its seconds to :attr:`metrics`."""
+        out = self.launched.pop(uids, None)
+        if self.faults is not None:
+            self.faults.before_compute(uids)
+        if out is None:
+            out = self._launch(images)
+        n = len(images)
+        ids = dict(self.tags, size=n,
+                   bucket=batching.bucket_for(self.buckets, n))
+        m = self.metrics
         t2 = time.perf_counter()
-        m.dispatch_s += t2 - t1
         with TraceAnnotation("marvel.serve.result_wait", **ids):
             logits = np.asarray(out)[:n]
         t3 = time.perf_counter()
@@ -315,8 +362,10 @@ class AsyncCnnEngine:
     the coalesce deadline, whichever first — and one compute thread runs the
     blocking jax dispatch so the event loop never stalls.  The batcher never
     awaits compute: it hands each batch to the compute thread and keeps
-    coalescing, so coalescing and jax dispatch pipeline.  The compute thread
-    hands a *finished batch* back to the event loop with ONE
+    coalescing, so coalescing and jax dispatch pipeline.  With a batch
+    staged behind the current one, the compute thread launches it before
+    waiting on the current result (the module's "Look-ahead").  The compute
+    thread hands a *finished batch* back to the event loop with ONE
     ``call_soon_threadsafe`` per flush, where every future in the batch
     resolves, in submission order, to its :class:`CnnRequest` —
     batch-granular resolution, not per-request loop round-trips.
@@ -348,6 +397,10 @@ class AsyncCnnEngine:
         self._batcher: asyncio.Task | None = None
         self._pool: concurrent.futures.ThreadPoolExecutor | None = None
         self._inflight: set = set()  # executor futures of dispatched batches
+        # (batch id, requests) of batches handed to the compute thread that
+        # it has not taken up yet, oldest first: appended by the event loop,
+        # popped by the compute thread, read there for the look-ahead
+        self._staged: collections.deque = collections.deque()
         # admitted requests whose future has not resolved yet — queued,
         # held in the batcher's coalescing batch, or in the compute thread
         self._live_reqs = 0
@@ -566,12 +619,16 @@ class AsyncCnnEngine:
         self._metrics.queue_wait_s += (len(batch) * loop.time()
                                        - sum(map(_ADMITTED, batch)))
         t_dispatch = time.perf_counter()
+        self._staged.append((batch_id, reqs))
 
         def compute_then_resolve():
-            # compute thread: the resilient blocking jax dispatch
-            # (retry/backoff + bisection), then ONE call_soon_threadsafe
-            # hands the finished batch to the loop
+            # compute thread: launch the next batch if one waits, then the
+            # resilient blocking jax dispatch (retry/backoff + bisection),
+            # then ONE call_soon_threadsafe hands the finished batch to the
+            # loop
             self._metrics.executor_wait_s += time.perf_counter() - t_dispatch
+            self._staged.popleft()  # this batch: the pool runs them in order
+            self._look_ahead(batch_id, reqs)
             retries = 0
             try:
                 outcomes, retries = _classify_resilient(
@@ -580,6 +637,8 @@ class AsyncCnnEngine:
                 err = None
             except Exception as e:  # WorkerDeath or a catastrophic failure
                 outcomes, err = None, e
+            # a classify that never reached the launched output leaves it
+            self.compute.launched.pop(tuple(r.uid for r in reqs), None)
             with TraceAnnotation("marvel.serve.handoff", batch=batch_id):
                 try:
                     loop.call_soon_threadsafe(
@@ -592,6 +651,29 @@ class AsyncCnnEngine:
         fut = loop.run_in_executor(self._pool, compute_then_resolve)
         self._inflight.add(fut)
         fut.add_done_callback(self._inflight.discard)
+
+    def _look_ahead(self, batch_id: int, reqs: list[CnnRequest]) -> None:
+        """Compute thread, before this batch's classify: where the next
+        batch is already staged, launch this batch (unless the look-ahead
+        before it did) and then the next one, so that the next batch's
+        stacking and input transfer run while the device computes this
+        one.  Where none is staged, classify runs the batch in turn.  A
+        launch that raises is dropped: each batch's own resilient path
+        launches it again."""
+        try:
+            next_id, next_reqs = self._staged[0]
+        except IndexError:
+            return
+        compute = self.compute
+        try:
+            compute.tags = {"batch": batch_id, "attempt": 0}
+            compute.launch([r.image for r in reqs], tuple(r.uid for r in reqs))
+            compute.tags = {"batch": next_id, "attempt": 0}
+            if compute.launch([r.image for r in next_reqs],
+                              tuple(r.uid for r in next_reqs)):
+                self._metrics.prefetched += 1
+        except Exception:
+            pass
 
     def _resolve_batch(self, loop, batch_id, batch, outcomes, retries, err,
                        deadline_flush: bool) -> None:

@@ -91,3 +91,13 @@ def test_counter_readers_without_the_counters_read_none():
         assert _reader(name)(_ctx(old, new)) is None, name
     same = {"batches": 10, "result_wait_s": 1.0}
     assert _reader("result_wait_ms_per_batch")(_ctx(same, same)) is None
+
+
+def test_prefetch_share_reads_the_counters_and_none_without_them():
+    before = {"batches": 10, "prefetched": 8}
+    after = {"batches": 50, "prefetched": 44}
+    assert _reader("prefetch_share")(_ctx(before, after)) == pytest.approx(
+        100.0 * 36 / 40)
+    parent = {"batches": 10, "completed": 320}  # no look-ahead counter
+    assert _reader("prefetch_share")(
+        _ctx(parent, dict(parent, batches=50))) is None

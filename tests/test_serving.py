@@ -8,6 +8,7 @@ warmed program never recompiles under traffic.  The multi-device DP smoke
 test only runs when ``jax.devices()`` has more than one entry.
 """
 import asyncio
+import threading
 import time
 from types import SimpleNamespace
 
@@ -343,27 +344,31 @@ def test_build_seconds_grow_on_a_bucket_miss_not_on_a_hit(lenet_prog):
 
 
 def test_serving_spans_in_a_profiler_trace(lenet_prog, tmp_path):
-    """Under the profiler each batch leaves every compute-thread span once,
-    joined by its batch id, and no compute-thread span encloses another."""
+    """Under the profiler, with batches staged so that the look-ahead
+    launches each next batch before the current result is read, each batch
+    leaves every compute-thread span once, joined by its batch id, on one
+    thread line, and no compute-thread span encloses another.  The trace
+    stops only after the engine has stopped: the last batch's ``handoff``
+    span closes after its futures resolve."""
     prog, _, _, in_shape = lenet_prog
     compute = {f"marvel.serve.{p}" for p in (
         "stack", "dispatch", "result_wait", "post", "handoff")}
 
     async def main():
-        async with prog.serve(mode="async", max_batch=4) as engine:
-            engine.warmup(in_shape)
-            jax.profiler.start_trace(str(tmp_path))
-            try:
-                for wave in range(3):
-                    await asyncio.gather(*[
-                        engine.submit(im)
-                        for im in _images(in_shape, 4, seed=wave)
-                    ])
-            finally:
-                jax.profiler.stop_trace()
-        return engine.metrics()["batches"]
+        engine = prog.serve(mode="async", max_batch=4)
+        await engine.start()
+        engine.warmup(in_shape)
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            await _held_then_released(engine, _images(in_shape, 12), 3)
+            await engine.stop()
+        finally:
+            jax.profiler.stop_trace()
+        return engine.metrics()
 
-    batches = asyncio.run(main())
+    m = asyncio.run(main())
+    batches = m["batches"]
+    assert batches == 3 and m["prefetched"] == 2
     (path,) = tmp_path.rglob("*.xplane.pb")
     spans = []  # ((plane, line), name, start, end, batch)
     for i, pl in enumerate(jax.profiler.ProfileData.from_file(str(path))
@@ -379,6 +384,178 @@ def test_serving_spans_in_a_profiler_trace(lenet_prog, tmp_path):
     on_line = sorted((s, e) for ln, n, s, e, _ in spans
                      if ln == line and n in compute)
     assert all(e <= s2 for (_, e), (s2, _) in zip(on_line, on_line[1:]))
+    start = {(n, b): s for _, n, s, _, b in spans}
+    for b in range(1, batches):  # launched before the result before it
+        assert (start["marvel.serve.dispatch", b]
+                < start["marvel.serve.result_wait", b - 1])
+
+
+async def _held_then_released(engine, images, batches):
+    """Submit ``images`` (uids 0, 1, ...) while the compute thread is held,
+    so that ``batches`` batches are staged before it takes up the first;
+    returns each request's result or exception."""
+    gate = threading.Event()
+    engine._pool.submit(gate.wait)
+    futs = [engine.submit_nowait(im, uid=i) for i, im in enumerate(images)]
+    for _ in range(5_000):
+        if len(engine._staged) >= batches:
+            break
+        await asyncio.sleep(0.001)
+    gate.set()
+    assert len(engine._staged) >= batches
+    return await asyncio.gather(*futs, return_exceptions=True)
+
+
+class _Out:
+    """A fake program output: records when it is read."""
+
+    def __init__(self, logits, calls, batch):
+        self.logits, self.calls, self.batch = logits, calls, batch
+
+    def copy_to_host_async(self):
+        pass
+
+    def __array__(self, dtype=None, copy=None):
+        self.calls.append(("read", self.batch))
+        return self.logits
+
+
+def test_look_ahead_launches_the_next_batch_before_the_result_read(
+        monkeypatch):
+    """Fake program, fake spans: with batches staged, batch N+1's stack
+    and dispatch come before batch N's result read, and ``prefetched``
+    counts them; one batch at a time, each batch runs stack, dispatch,
+    result wait, post, handoff in turn and ``prefetched`` stays 0."""
+    from repro.runtime import cnn_server
+
+    calls = []
+
+    class Span:
+        def __init__(self, name, **tags):
+            self.key = (name.removeprefix("marvel.serve."), tags["batch"])
+
+        def __enter__(self):
+            if self.key[0] != "resolve":  # the event loop's span
+                calls.append(self.key)
+
+        def __exit__(self, *exc):
+            return False
+
+    class Program:
+        dp_shards = 1
+
+        def __call__(self, x):
+            batch = int(x[0, 0]) // 4  # request i's image is all i
+            calls.append(("call", batch))
+            return _Out(np.repeat(x[:, :1], 3, axis=1), calls, batch)
+
+    monkeypatch.setattr(cnn_server, "TraceAnnotation", Span)
+    images = [np.full((2,), i, np.float32) for i in range(12)]
+
+    async def staged():
+        async with cnn_server.AsyncCnnEngine(Program(), max_batch=4) as e:
+            results = await _held_then_released(e, images, 3)
+            return results, e
+
+    results, engine = asyncio.run(staged())
+    assert [r.logits[0] for r in results] == list(range(12))
+    assert engine.metrics()["prefetched"] == 2
+    assert engine.compute.launched == {}
+
+    def launch(b):
+        return [("stack", b), ("dispatch", b), ("call", b)]
+
+    def finish(b):
+        return [("result_wait", b), ("read", b), ("post", b), ("handoff", b)]
+
+    assert calls == (launch(0) + launch(1) + finish(0)
+                     + launch(2) + finish(1) + finish(2))
+    calls.clear()
+
+    async def one_at_a_time():
+        async with cnn_server.AsyncCnnEngine(Program(), max_batch=4) as e:
+            for i in (0, 4):  # one request per batch, each awaited
+                await e.submit(images[i])
+            return e.metrics()
+
+    assert asyncio.run(one_at_a_time())["prefetched"] == 0
+    assert calls == launch(0) + finish(0) + launch(1) + finish(1)
+
+
+def test_look_ahead_logits_are_bit_identical_to_the_sync_engine(lenet_prog):
+    prog, _, _, in_shape = lenet_prog
+    images = _images(in_shape, 12, seed=3)
+
+    async def main():
+        async with prog.serve(mode="async", max_batch=4) as engine:
+            results = await _held_then_released(engine, images, 3)
+            return results, engine.metrics(), engine.compute.launched
+
+    results, m, launched = asyncio.run(main())
+    assert m["prefetched"] == 2 and launched == {}
+    sync = prog.serve(max_batch=4)
+    for i, im in enumerate(images):
+        sync.submit(i, im)
+    want = sync.run_until_drained()  # the same batches of four, in turn
+    for i, r in enumerate(results):
+        np.testing.assert_array_equal(r.logits, want[i].logits)
+
+
+def test_poison_pill_in_a_look_ahead_batch_is_isolated(lenet_prog):
+    """uid 5 is in batch 1, which the look-ahead launched: bisection still
+    fails exactly that request."""
+    from repro.runtime.batching import RetryPolicy
+    from repro.runtime.faults import FaultInjector, InjectedFault
+
+    prog, _, _, in_shape = lenet_prog
+    images = _images(in_shape, 12, seed=4)
+
+    async def main():
+        engine = prog.serve(
+            mode="async", max_batch=4, faults=FaultInjector(poison_uids=(5,)),
+            retry=RetryPolicy(max_retries=1, backoff_base_ms=0.1, jitter=0.0))
+        async with engine:
+            results = await _held_then_released(engine, images, 3)
+        return results, engine.metrics(), engine.compute.launched
+
+    results, m, launched = asyncio.run(main())
+    assert [i for i, r in enumerate(results)
+            if isinstance(r, Exception)] == [5]
+    assert isinstance(results[5], InjectedFault)
+    assert all(r.done for i, r in enumerate(results) if i != 5)
+    assert m["prefetched"] == 2 and m["errors"] == 1 and launched == {}
+
+
+def test_a_look_ahead_launch_that_raises_spares_the_batch_before_it(
+        lenet_prog):
+    """The program raises on its second call, the look-ahead launch of
+    batch 1: batch 0 still answers, and batch 1 launches again on its own
+    path and answers too."""
+    from repro.runtime.cnn_server import AsyncCnnEngine
+
+    prog, apply, params, in_shape = lenet_prog
+    images = _images(in_shape, 8, seed=5)
+    calls = []
+
+    class SecondCallRaises:
+        dp_shards = 1
+
+        def __call__(self, x):
+            calls.append(len(x))
+            if len(calls) == 2:
+                raise RuntimeError("launch failed")
+            return prog(x)
+
+    async def main():
+        async with AsyncCnnEngine(SecondCallRaises(), max_batch=4) as engine:
+            results = await _held_then_released(engine, images, 2)
+        return results, engine.metrics(), engine.compute.launched
+
+    results, m, launched = asyncio.run(main())
+    want = np.argmax(np.asarray(apply(params, np.stack(images))), axis=-1)
+    assert [r.label for r in results] == list(want)
+    assert m["errors"] == 0 and m["retries"] == 0
+    assert m["prefetched"] == 0 and launched == {} and len(calls) == 3
 
 
 def test_retry_after_hint_reads_compute_seconds_per_batch():
