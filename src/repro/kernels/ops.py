@@ -210,16 +210,21 @@ def _pallas_sep_block(x, w_dw, w_pw, *, stride=1, padding="SAME",
 
 
 def _pallas_matmul_epilogue(x, w, b=None, act="none", scale=None, shift=None,
-                            residual=None):
+                            residual=None, pre_scale=None, pre_shift=None):
+    """fusedmac: the GEMM with its epilogue; with ``pre_scale``/
+    ``pre_shift`` (a pre-activation BN-ReLU on x) the kernel's
+    ``preact_matmul`` variant applies that prologue per tile."""
     if residual is not None and not gemm_residual_fusable(x, w, residual):
         # mis-shaped skip tensor: stay on the algorithmically-fused oracle
         return _ref_fallback("matmul_epilogue", ref.matmul_epilogue_ref,
                              x, w, b, act=act, scale=scale, shift=shift,
-                             residual=residual)
+                             residual=residual, pre_scale=pre_scale,
+                             pre_shift=pre_shift)
     cfg = tuning.lookup("matmul_epilogue",
                         tuning.gemm_dims(x.shape, w.shape))
     return me.matmul_epilogue(x, w, b, act=act, scale=scale, shift=shift,
-                              residual=residual,
+                              residual=residual, pre_scale=pre_scale,
+                              pre_shift=pre_shift,
                               bm=cfg["bm"], bn=cfg["bn"], bk=cfg["bk"])
 
 

@@ -24,10 +24,14 @@ def mac_matmul_int8_ref(x_int8, w_int8, scale, out_dtype=jnp.float32):
 
 
 def matmul_epilogue_ref(x, w, b=None, act="none", scale=None, shift=None,
-                        residual=None):
-    y = jnp.einsum(
-        "...k,kn->...n", x.astype(jnp.float32), w.astype(jnp.float32)
-    )
+                        residual=None, pre_scale=None, pre_shift=None):
+    """GEMM oracle: the pre-activation prologue ``relu(x*pre_scale +
+    pre_shift)`` where either is given, then x @ w + bias + folded-BN
+    affine (+ residual) + act in f32."""
+    xf = x.astype(jnp.float32)
+    if pre_scale is not None or pre_shift is not None:
+        xf = preact_ref(xf, pre_scale, pre_shift)
+    y = jnp.einsum("...k,kn->...n", xf, w.astype(jnp.float32))
     if b is not None:
         y = y + b.astype(jnp.float32)
     if scale is not None:
@@ -37,6 +41,16 @@ def matmul_epilogue_ref(x, w, b=None, act="none", scale=None, shift=None,
     if residual is not None:
         y = y + residual.astype(jnp.float32)
     return _ACTS[act](y).astype(x.dtype)
+
+
+def preact_ref(x, pre_scale=None, pre_shift=None):
+    """The pre-activation prologue: a per-channel affine (a folded
+    batchnorm) and a relu on the GEMM's input."""
+    if pre_scale is not None:
+        x = x * pre_scale.astype(x.dtype)
+    if pre_shift is not None:
+        x = x + pre_shift.astype(x.dtype)
+    return jnp.maximum(x, 0.0)
 
 
 def fused_conv_ref(x, w, b=None, *, stride=1, padding="SAME", groups=1,

@@ -46,6 +46,7 @@ per-request futures)::
 """
 from __future__ import annotations
 
+import functools
 import time
 import warnings
 from dataclasses import dataclass, field
@@ -60,6 +61,14 @@ from repro.core.extensions import resolve_table
 from repro.core.pipeline import MarvelReport, build_report
 from repro.kernels import tuning as tuning_mod
 from repro.quant.ptq import fake_quantize_tree
+
+
+def _ref_fallback_total() -> int:
+    """Dispatch sites traced so far, in this process, whose kernel wrapper
+    took its jnp-reference branch (``repro.kernels.ops.REF_FALLBACKS``)."""
+    from repro.kernels import ops
+
+    return sum(ops.REF_FALLBACKS.values())
 
 
 def _bucket_key(args: tuple) -> tuple:
@@ -103,6 +112,9 @@ class MarvelProgram:
     cache_hits: int = 0
     cache_misses: int = 0
     build_s: float = 0.0  # seconds lowering + compiling on cache misses
+    # dispatch sites that fell back to their jnp oracle (a kernel lost to a
+    # guard) while the bucket executables were traced
+    ref_fallbacks: int = 0
     mesh: Any = None  # set by shard(); executables compile against it
     # the bound (possibly fake-quantized) parameter pytree, kept so
     # serve(mode="lm") can build decode engines without re-threading params
@@ -149,14 +161,21 @@ class MarvelProgram:
     def _executable_fn(self, *args) -> Callable:
         """What actually lowers: the table-bound fn, chess_rewritten for this
         shape bucket (the rewritten jaxpr is shape-specialized, so the pass
-        re-runs per bucket; it already succeeded on the example args)."""
+        re-runs per bucket; it already succeeded on the example args).
+
+        ``self.fn`` is traced behind a new function object: JAX caches a
+        trace by function and shapes, and the flow already traced
+        ``self.fn`` at the example's shapes, so a bucket of those shapes
+        would reuse that trace without running the model code, and its
+        dispatch sites would go uncounted in ``ref_fallbacks``."""
+        fresh = functools.wraps(self.fn)(lambda *a: self.fn(*a))
         if self.rewrite_baked:
             try:
-                fn, _ = rewrite_mod.rewrite(self.fn, *args)
+                fn, _ = rewrite_mod.rewrite(fresh, *args)
                 return fn
             except Exception:  # never lose the artifact to the optimizer
-                return self.fn
-        return self.fn
+                return fresh
+        return fresh
 
     def baked_jaxpr(self, *args):
         """The jaxpr of the program this bucket deploys — custom marvel_*
@@ -261,7 +280,9 @@ class MarvelProgram:
         if exe is None:
             self.cache_misses += 1
             t0 = time.perf_counter()
+            fallbacks = _ref_fallback_total()
             exe = self.lower(*args).compile()
+            self.ref_fallbacks += _ref_fallback_total() - fallbacks
             self.build_s += time.perf_counter() - t0
             self._cache[key] = exe
         else:
@@ -346,13 +367,15 @@ class MarvelProgram:
         return engines[mode](self, **engine_kwargs)
 
     def metrics(self) -> dict:
-        """Cache, build and shard counters, the program's slice of the serving
-        metrics surface (the engines merge this into theirs)."""
+        """Cache, build, reference-fallback and shard counters, the
+        program's slice of the serving metrics surface (the engines merge
+        this into theirs)."""
         return {
             "cache_hits": self.cache_hits,
             "cache_misses": self.cache_misses,
             "cache_size": self.cache_size,
             "build_s": self.build_s,
+            "ref_fallbacks": self.ref_fallbacks,
             "dp_shards": self.dp_shards,
         }
 

@@ -46,7 +46,8 @@ def _conv_ref(x, w, b, *, stride, padding, groups, act, scale=None,
     return ACTS[act](y)
 
 
-def _conv1x1_as_matmul(x, w, b, *, act, scale, shift, residual=None):
+def _conv1x1_as_matmul(x, w, b, *, act, scale, shift, residual=None,
+                       pre_scale=None, pre_shift=None):
     """A 1x1 stride-1 conv IS a GEMM over pixels — dispatch it as one.
 
     The (1, 1, Cin, Cout) kernel becomes a (Cin, Cout) matrix contracted
@@ -56,11 +57,13 @@ def _conv1x1_as_matmul(x, w, b, *, act, scale, shift, residual=None):
     (fusedmac) instead of an im2col conv (DenseNet/ResNet bottlenecks,
     MobileNetV2 expansions)."""
     return dense(x, w.reshape(w.shape[2], w.shape[3]), b, act=act,
-                 scale=scale, shift=shift, residual=residual)
+                 scale=scale, shift=shift, residual=residual,
+                 pre_scale=pre_scale, pre_shift=pre_shift)
 
 
 def conv2d(x, w, b=None, *, stride=1, padding="SAME", groups=1, act="none",
-           scale=None, shift=None, residual=None):
+           scale=None, shift=None, residual=None, pre_scale=None,
+           pre_shift=None):
     """Conv + bias + folded-BN affine (+ residual-add) + act: one
     conv_mac/fusedmac site.
 
@@ -72,13 +75,19 @@ def conv2d(x, w, b=None, *, stride=1, padding="SAME", groups=1, act="none",
     accumulates it in-register instead of round-tripping the conv output
     through HBM.  1x1 stride-1 convs are rerouted to the matmul_epilogue
     pattern at trace time (see :func:`_conv1x1_as_matmul`) — they are
-    GEMMs, not convolutions.
+    GEMMs, not convolutions.  ``pre_scale``/``pre_shift`` carry a
+    pre-activation BN-ReLU of the conv's *input* (DenseNet's BN-ReLU-conv)
+    into the GEMM as its prologue; only those 1x1 GEMMs take one.
     """
     if (groups == 1 and x.ndim == 4 and stride == 1
             and w.shape[0] == w.shape[1] == 1
             and padding in ("SAME", "VALID")):
         return _conv1x1_as_matmul(x, w, b, act=act, scale=scale, shift=shift,
-                                  residual=residual)
+                                  residual=residual, pre_scale=pre_scale,
+                                  pre_shift=pre_shift)
+    if pre_scale is not None or pre_shift is not None:
+        raise ValueError("a pre-activation prologue rides only 1x1 "
+                         "stride-1 convs (GEMMs)")
     return dispatch.call(
         "fused_conv", _conv_ref, x, w, b,
         stride=stride, padding=padding, groups=groups, act=act,
@@ -136,7 +145,14 @@ def sep_block(x, w_dw, w_pw, *, stride=1, padding="SAME", dw_scale=None,
     )
 
 
-def _dense_ref(x, w, b, *, act, scale=None, shift=None, residual=None):
+def _dense_ref(x, w, b, *, act, scale=None, shift=None, residual=None,
+               pre_scale=None, pre_shift=None):
+    if pre_scale is not None or pre_shift is not None:
+        # the kernels' oracle owns the prologue's semantics (as pool_ref
+        # owns the pools'); lazy for the same reason as in _pool_ref
+        from repro.kernels.ref import preact_ref
+
+        x = preact_ref(x, pre_scale, pre_shift)
     y = x @ w
     if b is not None:
         y = y + b
@@ -149,11 +165,15 @@ def _dense_ref(x, w, b, *, act, scale=None, shift=None, residual=None):
     return ACTS[act](y)
 
 
-def dense(x, w, b=None, *, act="none", scale=None, shift=None, residual=None):
+def dense(x, w, b=None, *, act="none", scale=None, shift=None, residual=None,
+          pre_scale=None, pre_shift=None):
     """GEMM + bias + optional folded-BN affine (+ residual-add) + act: one
-    fusedmac site (the residual rides the acc_mac epilogue at v3+)."""
+    fusedmac site (the residual rides the acc_mac epilogue at v3+).
+    ``pre_scale``/``pre_shift`` make ``relu(x*pre_scale + pre_shift)`` the
+    GEMM's input inside the same site (a pre-activation prologue)."""
     return dispatch.call("matmul_epilogue", _dense_ref, x, w, b, act=act,
-                         scale=scale, shift=shift, residual=residual)
+                         scale=scale, shift=shift, residual=residual,
+                         pre_scale=pre_scale, pre_shift=pre_shift)
 
 
 def _pool_ref(x, *, op, k=2, stride=2):
@@ -474,23 +494,24 @@ def densenet121_init(key):
 
 
 def densenet121_apply(p, x):
-    # stem is the only post-conv BN+act chain; the dense layers are
-    # pre-activation (BN-relu-conv), which stays outside the conv epilogue
+    # the dense layers are pre-activation (BN-relu-conv): each 1x1 GEMM
+    # takes the BN-relu before it as its prologue and, in a bottleneck, the
+    # BN-relu after it as its epilogue, so both ride one matmul_epilogue
+    # site; the 3x3 conv and the concatenation stay outside it
     x = conv2d(x, p["stem"]["w"], stride=2, scale=p["stem"]["bn"]["s"],
                shift=p["stem"]["bn"]["b"], act="relu")
     x = maxpool(x, 3, 2)
     for block in p["blocks"]:
         for lyr in block["layers"]:
-            y = ACTS["relu"](_affine(x, lyr["bn1"]["s"], lyr["bn1"]["b"]))
-            y = conv2d(y, lyr["c1"]["w"])
-            y = ACTS["relu"](_affine(y, lyr["bn2"]["s"], lyr["bn2"]["b"]))
+            y = conv2d(x, lyr["c1"]["w"], pre_scale=lyr["bn1"]["s"],
+                       pre_shift=lyr["bn1"]["b"], scale=lyr["bn2"]["s"],
+                       shift=lyr["bn2"]["b"], act="relu")
             y = conv2d(y, lyr["c2"]["w"])
             x = jnp.concatenate([x, y], axis=-1)
         if "trans" in block:
-            x = ACTS["relu"](
-                _affine(x, block["trans"]["bn"]["s"], block["trans"]["bn"]["b"])
-            )
-            x = conv2d(x, block["trans"]["w"])
+            x = conv2d(x, block["trans"]["w"],
+                       pre_scale=block["trans"]["bn"]["s"],
+                       pre_shift=block["trans"]["bn"]["b"])
             x = avgpool2(x)
     x = ACTS["relu"](_affine(x, p["bn_f"]["s"], p["bn_f"]["b"]))
     x = avgpool_global(x)

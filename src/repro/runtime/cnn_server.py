@@ -52,7 +52,8 @@ and ``attempt`` (retries and bisection call the program again).  The
 async engine also counts ``queue_wait_s`` (admission to dispatch, summed
 over requests) and ``executor_wait_s`` (dispatch to the compute thread
 taking the batch, summed over batches); ``build_s`` is the program's
-seconds compiling bucket executables.
+seconds compiling bucket executables, and ``ref_fallbacks`` its dispatch
+sites that fell back to the jnp oracle while those were traced.
 
 Look-ahead (async engine)
 -------------------------
@@ -759,12 +760,13 @@ class AsyncCnnEngine:
 
 
 def _program_metrics(program) -> dict:
-    """Cache hit/miss, build seconds and shard counters re-exported from the
-    MarvelProgram."""
+    """Cache hit/miss, build seconds, reference fallbacks and shard
+    counters re-exported from the MarvelProgram."""
     return {
         "cache_hits": getattr(program, "cache_hits", 0),
         "cache_misses": getattr(program, "cache_misses", 0),
         "cache_size": getattr(program, "cache_size", 0),
         "build_s": getattr(program, "build_s", 0.0),
+        "ref_fallbacks": getattr(program, "ref_fallbacks", 0),
         "dp_shards": int(getattr(program, "dp_shards", 1) or 1),
     }

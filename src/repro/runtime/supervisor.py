@@ -624,7 +624,8 @@ class Supervisor:
                "deadline_flushes", "full_flushes", "loop_handoffs", "errors",
                "retries", "shed", "deadline_failures",
                "stack_s", "dispatch_s", "result_wait_s", "post_s",
-               "queue_wait_s", "executor_wait_s", "build_s", "prefetched",
+               "queue_wait_s", "executor_wait_s", "build_s", "ref_fallbacks",
+               "prefetched",
                "tokens_total", "prefill_tokens", "decode_steps", "replays",
                "compile_hits", "compile_misses", "kv_slot_reuses",
                "queue_depth", "running_sequences", "kv_slots_used",

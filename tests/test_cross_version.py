@@ -61,22 +61,33 @@ def test_logits_agree_across_all_versions(name, in_shape, tol):
         assert rel < tol, (name, lvl, rel)
 
 
-@pytest.mark.parametrize("name", ["lenet5", "vgg16", "resnet50"])
-def test_v4_dispatch_zero_baseline_conv_and_pool_sites(name, monkeypatch):
+@pytest.mark.parametrize("name,batch_shape", [
+    pytest.param("lenet5", None, id="lenet5"),
+    pytest.param("vgg16", None, id="vgg16"),
+    pytest.param("resnet50", None, id="resnet50"),
+    # DenseNet at its benchmark size: odd grids (55, 27, 13, 6) and
+    # concatenated maps of every width 64 + 32i (shape-only trace)
+    pytest.param("densenet121", (32, 224, 224, 3), id="densenet121-224"),
+])
+def test_v4_dispatch_zero_baseline_conv_and_pool_sites(name, batch_shape,
+                                                        monkeypatch):
     """Acceptance: at v4/pallas every conv, GEMM, and pool site in the
-    plain + residual CNNs reaches its kernel — the jnp fallbacks inside the
-    wrappers are never taken."""
+    plain + residual + densely connected CNNs reaches its kernel — the jnp
+    fallbacks inside the wrappers are never taken — and DenseNet's 61
+    pre-activated 1x1 GEMMs take the kernel's prologue variant."""
     init, apply, in_shape = cnn.get_cnn(name)
-    p = init(jax.random.PRNGKey(0))
-    x = jnp.zeros((1, *in_shape))
-    sites = profiler.profile_fn(lambda x: apply(p, x), x).site_counts
+    p = jax.eval_shape(init, jax.random.PRNGKey(0))
+    x = jax.ShapeDtypeStruct(batch_shape or (1, *in_shape), jnp.float32)
+    sites = profiler.profile_fn(apply, p, x).site_counts
 
-    kernel_calls = {"conv": [], "gemm": [], "pool": []}
+    kernel_calls = {"conv": [], "gemm": [], "pool": [], "preact": []}
     fallbacks = []
 
     def counting(bucket, real):
         def wrapped(*a, **k):
             kernel_calls[bucket].append(1)
+            if k.get("pre_scale") is not None:
+                kernel_calls["preact"].append(1)
             return real(*a, **k)
         return wrapped
 
@@ -97,7 +108,7 @@ def test_v4_dispatch_zero_baseline_conv_and_pool_sites(name, monkeypatch):
         monkeypatch.setattr(ref, rname, falling(getattr(ref, rname), rname))
 
     with dispatch.use_table(resolve_table("v4", "pallas", model_class="cnn")):
-        jax.eval_shape(lambda x: apply(p, x), x)
+        jax.eval_shape(apply, p, x)
 
     assert not fallbacks, fallbacks  # the acceptance criterion
     absorbed = sites["sep_block"]  # none in these three models
@@ -106,6 +117,8 @@ def test_v4_dispatch_zero_baseline_conv_and_pool_sites(name, monkeypatch):
     assert len(kernel_calls["pool"]) == sites["pool"]
     if name != "lenet5":  # lenet5's stride-2 convs subsume pooling
         assert sites["pool"] > 0
+    # 58 bottlenecks and 3 transitions in DenseNet-121, none elsewhere
+    assert len(kernel_calls["preact"]) == (61 if name == "densenet121" else 0)
 
 
 @pytest.mark.parametrize("name", ["mobilenetv1", "mobilenetv2",

@@ -101,3 +101,36 @@ def test_prefetch_share_reads_the_counters_and_none_without_them():
     parent = {"batches": 10, "completed": 320}  # no look-ahead counter
     assert _reader("prefetch_share")(
         _ctx(parent, dict(parent, batches=50))) is None
+
+
+def test_ref_fallback_sites_reads_the_counter_and_none_without_it():
+    before = {"batches": 0, "ref_fallbacks": 0, "build_s": 16.0}
+    assert _reader("ref_fallback_sites")(_ctx(before, before)) == 0
+    assert _reader("ref_fallback_sites")(
+        _ctx(dict(before, ref_fallbacks=3), before)) == 3
+    parent = {"batches": 0, "build_s": 16.0}  # no counter
+    assert _reader("ref_fallback_sites")(_ctx(parent, parent)) is None
+
+
+def _roofline_ctx(trace, sites):
+    return SimpleNamespace(
+        trace=trace, site_work=lambda batch: sites,
+        peaks={"int8_ops_per_s": 100.0, "hbm_bytes_per_s": 10.0},
+        cell=SimpleNamespace(traffic={"serving": {"buckets": [32]}}))
+
+
+def test_preact_matmul_roofline_reads_the_kernel_and_none_without_it():
+    # two sites a step, each least 2 s (bytes bound); 6 calls are 3 steps,
+    # so 12 s of least time in 48 s of kernel time
+    sites = [{"kernel": "preact_matmul", "flops": 100.0, "bytes": 20.0}] * 2 \
+        + [{"kernel": "fused_conv", "flops": 1e6, "bytes": 1e6}]
+    trace = SimpleNamespace(kernel_calls={"preact_matmul": (6, 48.0),
+                                          "fused_conv": (3, 1e9)})
+    read = _reader("preact_matmul_roofline")
+    assert read(_roofline_ctx(trace, sites)) == pytest.approx(25.0)
+    # an untraced run; a parent whose trace has no such kernel; a program
+    # whose reference has no such site
+    assert read(_roofline_ctx(None, sites)) is None
+    parent = SimpleNamespace(kernel_calls={"matmul_epilogue": (6, 48.0)})
+    assert read(_roofline_ctx(parent, sites)) is None
+    assert read(_roofline_ctx(trace, sites[2:])) is None

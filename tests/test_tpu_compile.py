@@ -5,7 +5,8 @@ Mosaic refuses breaks every program of its class there — and interpret mode
 (what the rest of the suite runs) cannot see a refusal: unaligned or
 strided sublane loads, block shapes off the (8, 128) tiling, scoped-VMEM
 overruns.  Each case lowers the kernel at the shapes the CNN serving path
-uses (ResNet-50 at 224x224, batch 8) or the LM classes use, compiles it for
+uses (ResNet-50 at 224x224, batch 8; DenseNet-121's own shapes at batch
+32) or the LM classes use, compiles it for
 a ``v5e:2x2`` topology description, and checks the executable holds the
 kernel as a ``tpu_custom_call``.
 
@@ -75,6 +76,11 @@ def _conv_args(x, w):
     return [(x, I8), (w, I8), ((cout,), F32), ((cout,), F32)]
 
 
+def _preact(x, w, ps, pt, s, t):
+    return me.matmul_epilogue(x, w, act="relu", scale=s, shift=t,
+                              pre_scale=ps, pre_shift=pt)
+
+
 # name -> (kernel name in the executable, fn, [(shape, dtype), ...])
 CASES = {
     "fused_conv-stem224": ("fused_conv", _conv(2),
@@ -119,6 +125,27 @@ CASES = {
                                               residual=r),
         [((8, 56, 56, 64), F32), ((64, 256), F32), ((256,), F32),
          ((8, 56, 56, 256), F32)]),
+    # DenseNet-121 at 224x224, batch 32: the pre-activated 1x1 GEMM over a
+    # block-1 map (M = 32*55*55, K read whole and unpadded, the last M
+    # block ragged) at its narrowest and widest K, the 3x3 convs to 32
+    # channels at the first and last grid, and a transition pool on an odd
+    # grid
+    "preact_matmul-K96": (
+        "preact_matmul", _preact,
+        [((32, 55, 55, 96), F32), ((96, 128), F32)] + [((96,), F32)] * 2
+        + [((128,), F32)] * 2),
+    "preact_matmul-K992": (
+        "preact_matmul", _preact,
+        [((32, 55, 55, 992), F32), ((992, 128), F32)] + [((992,), F32)] * 2
+        + [((128,), F32)] * 2),
+    "fused_conv-3x3-128to32-55": ("fused_conv", _conv(1),
+                                  _conv_args((32, 55, 55, 128),
+                                             (3, 3, 128, 32))),
+    "fused_conv-3x3-128to32-6": ("fused_conv", _conv(1),
+                                 _conv_args((32, 6, 6, 128),
+                                            (3, 3, 128, 32))),
+    "avgpool-2x2s2-55": ("avgpool", lambda x: pk.avgpool2d(x, k=2, stride=2),
+                         [((32, 55, 55, 128), F32)]),
     "mac_matmul_int8": ("mac_matmul_int8", mm.mac_matmul_int8,
                         [((256, 2048), I8), ((2048, 2048), I8),
                          ((2048,), F32)]),
