@@ -244,12 +244,14 @@ class EngineMetrics:
     # == completed (one per request) — asserted by tests and bench_serving
     loop_handoffs: int = 0
     # cumulative seconds by phase, the same boundaries as the
-    # ``marvel.serve.*`` spans: the compute thread's stack (+pad), dispatch,
-    # result wait (device run + device-to-host copy) and post-processing
-    # (CNN engines), each request's wait from admission to its batch's
-    # dispatch, and each batch's wait for the compute thread (async
-    # engine).  Each is written by one thread only: the compute thread, or
-    # the event loop for queue_wait_s
+    # ``marvel.serve.*`` spans: stack (+pad, and for a staged batch the
+    # start of its transfer), dispatch, result wait (device run +
+    # device-to-host copy) and post-processing (CNN engines), each
+    # request's wait from admission to its batch's dispatch, and each
+    # batch's wait for the compute thread (async engine).  Each is written
+    # by one thread only: the compute thread, or the event loop for
+    # queue_wait_s (a staged batch's stack seconds, timed on the loop, are
+    # added when the compute thread launches it)
     stack_s: float = 0.0
     dispatch_s: float = 0.0
     result_wait_s: float = 0.0
@@ -259,6 +261,9 @@ class EngineMetrics:
     # batches the async engine's compute thread launched while the batch
     # before them was still in flight (its one-batch look-ahead)
     prefetched: int = 0
+    # launches that found their batch's input already on the device, put
+    # there by the async engine's event loop at dispatch
+    staged_inputs: int = 0
     _latencies_ms: Reservoir = field(default_factory=Reservoir)
 
     def observe_latency(self, ms: float) -> None:
@@ -301,6 +306,7 @@ class EngineMetrics:
             "queue_wait_s": self.queue_wait_s,
             "executor_wait_s": self.executor_wait_s,
             "prefetched": self.prefetched,
+            "staged_inputs": self.staged_inputs,
             "p50_latency_ms": self.latency_ms(50),
             "p99_latency_ms": self.latency_ms(99),
         }

@@ -41,35 +41,48 @@ Tracing
 -------
 Each phase of a batch is a ``jax.profiler.TraceAnnotation`` span, inert
 unless a profiler is running, and adds its seconds to a counter on
-``metrics()``.  On the compute thread (leaf spans, never nested):
-``marvel.serve.stack`` (``stack_s``), ``marvel.serve.dispatch``
-(``dispatch_s``), ``marvel.serve.result_wait`` (``result_wait_s``: the
-device run and the device-to-host copy), ``marvel.serve.post``
-(``post_s``) and, async engine, ``marvel.serve.handoff``; on the event
-loop ``marvel.serve.resolve``.  Every span carries ``batch``, the id the
-engine gave the batch; the compute spans also carry ``size``, ``bucket``
-and ``attempt`` (retries and bisection call the program again).  The
-async engine also counts ``queue_wait_s`` (admission to dispatch, summed
-over requests) and ``executor_wait_s`` (dispatch to the compute thread
-taking the batch, summed over batches); ``build_s`` is the program's
-seconds compiling bucket executables, and ``ref_fallbacks`` its dispatch
-sites that fell back to the jnp oracle while those were traced.
+``metrics()``.  Leaf spans, never nested: ``marvel.serve.stack``
+(``stack_s``: on the event loop for a batch the async engine staged, on
+the compute thread otherwise), and on the compute thread
+``marvel.serve.dispatch`` (``dispatch_s``), ``marvel.serve.result_wait``
+(``result_wait_s``: the device run and the device-to-host copy),
+``marvel.serve.post`` (``post_s``) and, async engine,
+``marvel.serve.handoff``; on the event loop ``marvel.serve.resolve``.
+Every span carries ``batch``, the id the engine gave the batch; the
+stack, dispatch, result wait and post spans also carry ``size``,
+``bucket`` and ``attempt`` (retries and bisection call the program
+again).  The async engine also counts ``queue_wait_s`` (admission to
+dispatch, summed over requests) and ``executor_wait_s`` (dispatch to the
+compute thread taking the batch, summed over batches); ``build_s`` is the
+program's seconds compiling bucket executables, and ``ref_fallbacks`` its
+dispatch sites that fell back to the jnp oracle while those were traced.
+
+Staging (async engine)
+----------------------
+When the event loop coalesces a batch, it stacks and pads the images and
+starts their transfer to the device (``jax.device_put`` with the input
+sharding of the bucket's executable, not waited on) before handing the
+batch to the compute thread.  The batch's launch then only calls the
+program on the device array.  A batch is staged once its bucket's
+executable is known (warmed, or launched once); a launch that finds no
+staged input (the first batch of a bucket not warmed, retries, bisection
+sub-batches, the sync engine) stacks on the compute thread.
+``staged_inputs`` on ``metrics()`` counts the launches that found one.
 
 Look-ahead (async engine)
 -------------------------
-When the compute thread takes up batch N and batch N+1 is already staged
+When the compute thread takes up batch N and batch N+1 is already waiting
 behind it, it launches N (unless already launched), then N+1, and only
-then waits on N: a launch is the stack and the program call (argument
-transfer and enqueue) plus the start of the result's copy to the host.
-N+1's stacking and input transfer so run while the device computes N,
-and N+1's executable is queued on the device behind N.  The depth is one
-batch; with no batch staged, the thread runs each batch in turn as the
-sync engine does.  The compute thread's spans then read, per batch
-taken up: ``stack`` and ``dispatch`` of N+1, then ``result_wait``,
-``post`` and ``handoff`` of N (``stack`` and ``dispatch`` of N too,
-where no look-ahead launched it), each batch leaving each span once.
-``prefetched`` on ``metrics()`` counts the batches launched while the
-batch before them was still in flight.
+then waits on N: a launch is the program call (and, for a batch not
+staged, the stack before it) plus the start of the result's copy to the
+host.  N+1's executable is so queued on the device behind N.  The depth
+is one batch; with no batch waiting, the thread runs each batch in turn
+as the sync engine does.  The compute thread's spans then read, per
+batch taken up: ``dispatch`` of N+1, then ``result_wait``, ``post`` and
+``handoff`` of N (``dispatch`` of N too, where no look-ahead launched
+it), each batch leaving each span once.  ``prefetched`` on ``metrics()``
+counts the batches launched while the batch before them was still in
+flight.
 """
 from __future__ import annotations
 
@@ -135,6 +148,13 @@ class _BucketedCompute:
         # outputs of batches launched ahead of their classify call, by the
         # batch's uids; each is popped by the first classify for its uids
         self.launched: dict[tuple[int, ...], object] = {}
+        # batches' inputs put on the device by stage(), with the seconds
+        # stacking them took, by the batch's uids; each is popped by the
+        # first launch for its uids
+        self.device_inputs: dict[tuple[int, ...], tuple] = {}
+        # the input sharding of each known bucket executable, by the
+        # batch's (shape, dtype): where stage() places a batch
+        self.placements: dict[tuple, jax.sharding.Sharding] = {}
 
     def warmup(self, in_shape: tuple[int, ...], dtype="float32") -> None:
         """Pre-compile AND prime every batch bucket: build the AOT
@@ -143,29 +163,73 @@ class _BucketedCompute:
         here, not by the first live request."""
         for b in self.buckets:
             spec = jax.ShapeDtypeStruct((b, *in_shape), np.dtype(dtype))
-            exe = self.program.executable_for(spec)
+            exe = self._executable(spec)
             jax.block_until_ready(exe(np.zeros((b, *in_shape),
                                                np.dtype(dtype))))
         spec = (tuple(in_shape), str(np.dtype(dtype)))
         if spec not in self.warmed:
             self.warmed.append(spec)
 
-    def _launch(self, images: list[np.ndarray]):
-        """The first half of a batch: stack + pad, then the program call
-        (argument transfer and enqueue, no wait) and the start of the
-        result's copy to the host.  Returns the program's output, a
-        ``jax.Array``."""
+    def _executable(self, x):
+        """The program's executable for ``x``'s bucket (the program itself
+        where it builds none), recording its input sharding so that
+        :meth:`stage` can place later batches of the bucket."""
+        build = getattr(self.program, "executable_for", None)
+        if build is None:
+            return self.program
+        exe = build(x)
+        key = (tuple(x.shape), np.dtype(x.dtype))
+        if key not in self.placements:
+            self.placements[key] = exe.input_shardings[0][0]
+        return exe
+
+    def stage(self, images: list[np.ndarray], uids: tuple[int, ...],
+              batch: int) -> None:
+        """The event loop's half of a batch, at its dispatch: stack + pad,
+        then start the input's transfer to the device, placed as the
+        bucket's executable takes it, without waiting on it.  The launch
+        for ``uids`` picks the device array up.  Stages nothing where the
+        bucket's executable is not known yet or stacking raises: that
+        launch then stacks the batch itself."""
+        n = len(images)
+        bucket = batching.bucket_for(self.buckets, n)
+        first = images[0]
+        placement = self.placements.get(((bucket, *first.shape), first.dtype))
+        if placement is None:
+            return
+        t0 = time.perf_counter()
+        with TraceAnnotation("marvel.serve.stack", batch=batch, attempt=0,
+                             size=n, bucket=bucket):
+            try:
+                x = jax.device_put(
+                    batching.pad_batch(np.stack(images), bucket), placement)
+            except Exception:  # the launch stacks again, and the
+                return  # resilient path reports what raises there
+        self.device_inputs[uids] = (x, time.perf_counter() - t0)
+
+    def _launch(self, images: list[np.ndarray], uids: tuple[int, ...]):
+        """The first half of a batch: the input :meth:`stage` put on the
+        device for ``uids``, else stack + pad here; then the program call
+        (enqueue, and the argument transfer of a host array, no wait) and
+        the start of the result's copy to the host.  Returns the program's
+        output, a ``jax.Array``."""
         n = len(images)
         bucket = batching.bucket_for(self.buckets, n)
         ids = dict(self.tags, size=n, bucket=bucket)
         m = self.metrics
-        t0 = time.perf_counter()
-        with TraceAnnotation("marvel.serve.stack", **ids):
-            x = batching.pad_batch(np.stack(images), bucket)
+        staged = self.device_inputs.pop(uids, None)
+        if staged is None:
+            t0 = time.perf_counter()
+            with TraceAnnotation("marvel.serve.stack", **ids):
+                x = batching.pad_batch(np.stack(images), bucket)
+            stack_s = time.perf_counter() - t0
+        else:
+            x, stack_s = staged
+            m.staged_inputs += 1
+        m.stack_s += stack_s
         t1 = time.perf_counter()
-        m.stack_s += t1 - t0
         with TraceAnnotation("marvel.serve.dispatch", **ids):
-            out = self.program(x)
+            out = self._executable(x)(x)
             out.copy_to_host_async()
         m.dispatch_s += time.perf_counter() - t1
         return out
@@ -177,7 +241,7 @@ class _BucketedCompute:
         where a launched batch already holds these ``uids``."""
         if uids in self.launched:
             return False
-        self.launched[uids] = self._launch(images)
+        self.launched[uids] = self._launch(images, uids)
         return True
 
     def classify(self, images: list[np.ndarray], uids: tuple[int, ...] = ()
@@ -193,7 +257,7 @@ class _BucketedCompute:
         if self.faults is not None:
             self.faults.before_compute(uids)
         if out is None:
-            out = self._launch(images)
+            out = self._launch(images, uids)
         n = len(images)
         ids = dict(self.tags, size=n,
                    bucket=batching.bucket_for(self.buckets, n))
@@ -362,14 +426,16 @@ class AsyncCnnEngine:
     coalesces requests into pow-2 buckets — flushing on a full bucket or on
     the coalesce deadline, whichever first — and one compute thread runs the
     blocking jax dispatch so the event loop never stalls.  The batcher never
-    awaits compute: it hands each batch to the compute thread and keeps
-    coalescing, so coalescing and jax dispatch pipeline.  With a batch
-    staged behind the current one, the compute thread launches it before
-    waiting on the current result (the module's "Look-ahead").  The compute
-    thread hands a *finished batch* back to the event loop with ONE
-    ``call_soon_threadsafe`` per flush, where every future in the batch
-    resolves, in submission order, to its :class:`CnnRequest` —
-    batch-granular resolution, not per-request loop round-trips.
+    awaits compute: it stacks each batch, starts its input's transfer to
+    the device (the module's "Staging"), hands the batch to the compute
+    thread and keeps coalescing, so coalescing, input transfer and jax
+    dispatch pipeline.  With a batch waiting behind the current one, the
+    compute thread launches it before waiting on the current result (the
+    module's "Look-ahead").  The compute thread hands a *finished batch*
+    back to the event loop with ONE ``call_soon_threadsafe`` per flush,
+    where every future in the batch resolves, in submission order, to its
+    :class:`CnnRequest` — batch-granular resolution, not per-request loop
+    round-trips.
 
     Failure semantics: requests whose ``deadline_ms`` expired before
     dispatch fast-fail with :class:`DeadlineExceeded`; compute failures go
@@ -453,6 +519,8 @@ class AsyncCnnEngine:
         if self._pool is not None:
             self._pool.shutdown(wait=False, cancel_futures=True)
             self._pool = None
+        self.compute.launched.clear()
+        self.compute.device_inputs.clear()
         err = WorkerUnavailable(f"worker killed: {reason}")
         for fut in list(self._unresolved):
             if not fut.done():
@@ -610,15 +678,20 @@ class AsyncCnnEngine:
             self._inflight.difference_update(inflight)
 
     def _dispatch(self, loop, batch, deadline_flush: bool) -> None:
-        """Hand one coalesced batch to the compute thread and return
-        immediately (the batcher keeps coalescing while compute runs)."""
+        """Stage one coalesced batch's input on the device, hand the batch
+        to the compute thread and return without waiting (the batcher
+        keeps coalescing while compute runs)."""
         reqs = [b[0] for b in batch]
+        uids = tuple(r.uid for r in reqs)
         batch_id = self._dispatched
         self._dispatched += 1
         # summed over the batch: now less each request's admission time
         # (both on the loop's clock)
         self._metrics.queue_wait_s += (len(batch) * loop.time()
                                        - sum(map(_ADMITTED, batch)))
+        # before the compute thread can see the batch: its launch, or the
+        # look-ahead's, then finds the staged input
+        self.compute.stage([r.image for r in reqs], uids, batch_id)
         t_dispatch = time.perf_counter()
         self._staged.append((batch_id, reqs))
 
@@ -638,8 +711,10 @@ class AsyncCnnEngine:
                 err = None
             except Exception as e:  # WorkerDeath or a catastrophic failure
                 outcomes, err = None, e
-            # a classify that never reached the launched output leaves it
-            self.compute.launched.pop(tuple(r.uid for r in reqs), None)
+            # a classify that never reached the launched output or the
+            # staged input leaves them
+            self.compute.launched.pop(uids, None)
+            self.compute.device_inputs.pop(uids, None)
             with TraceAnnotation("marvel.serve.handoff", batch=batch_id):
                 try:
                     loop.call_soon_threadsafe(
@@ -655,12 +730,11 @@ class AsyncCnnEngine:
 
     def _look_ahead(self, batch_id: int, reqs: list[CnnRequest]) -> None:
         """Compute thread, before this batch's classify: where the next
-        batch is already staged, launch this batch (unless the look-ahead
+        batch is already waiting, launch this batch (unless the look-ahead
         before it did) and then the next one, so that the next batch's
-        stacking and input transfer run while the device computes this
-        one.  Where none is staged, classify runs the batch in turn.  A
-        launch that raises is dropped: each batch's own resilient path
-        launches it again."""
+        executable is queued on the device behind this one.  Where none is
+        waiting, classify runs the batch in turn.  A launch that raises is
+        dropped: each batch's own resilient path launches it again."""
         try:
             next_id, next_reqs = self._staged[0]
         except IndexError:

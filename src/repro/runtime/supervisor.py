@@ -625,7 +625,7 @@ class Supervisor:
                "retries", "shed", "deadline_failures",
                "stack_s", "dispatch_s", "result_wait_s", "post_s",
                "queue_wait_s", "executor_wait_s", "build_s", "ref_fallbacks",
-               "prefetched",
+               "prefetched", "staged_inputs",
                "tokens_total", "prefill_tokens", "decode_steps", "replays",
                "compile_hits", "compile_misses", "kv_slot_reuses",
                "queue_depth", "running_sequences", "kv_slots_used",

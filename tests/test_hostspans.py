@@ -103,6 +103,21 @@ def test_prefetch_share_reads_the_counters_and_none_without_them():
         _ctx(parent, dict(parent, batches=50))) is None
 
 
+def test_staged_input_share_reads_the_counters_and_none_without_them():
+    before = {"batches": 10, "staged_inputs": 9}
+    after = {"batches": 50, "staged_inputs": 48}
+    assert _reader("staged_input_share")(_ctx(before, after)) == (
+        pytest.approx(100.0 * 39 / 40))
+    parent = {"batches": 10, "prefetched": 8}  # no staging counter
+    assert _reader("staged_input_share")(
+        _ctx(parent, dict(parent, batches=50, prefetched=48))) is None
+
+
+def test_staged_input_share_reads_none_without_batches_in_the_window():
+    same = {"batches": 10, "staged_inputs": 10}
+    assert _reader("staged_input_share")(_ctx(same, same)) is None
+
+
 def test_ref_fallback_sites_reads_the_counter_and_none_without_it():
     before = {"batches": 0, "ref_fallbacks": 0, "build_s": 16.0}
     assert _reader("ref_fallback_sites")(_ctx(before, before)) == 0

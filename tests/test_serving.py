@@ -344,15 +344,18 @@ def test_build_seconds_grow_on_a_bucket_miss_not_on_a_hit(lenet_prog):
 
 
 def test_serving_spans_in_a_profiler_trace(lenet_prog, tmp_path):
-    """Under the profiler, with batches staged so that the look-ahead
+    """Under the profiler, with batches waiting so that the look-ahead
     launches each next batch before the current result is read, each batch
-    leaves every compute-thread span once, joined by its batch id, on one
-    thread line, and no compute-thread span encloses another.  The trace
-    stops only after the engine has stopped: the last batch's ``handoff``
-    span closes after its futures resolve."""
+    leaves every span once, joined by its batch id: the compute thread's
+    on one thread line, none enclosing another, and its ``stack`` on the
+    event loop's line beside ``resolve``, closed before its ``dispatch``
+    opens (a warmed engine stages every batch).  The trace stops only
+    after the engine has stopped: the last batch's ``handoff`` span closes
+    after its futures resolve."""
     prog, _, _, in_shape = lenet_prog
     compute = {f"marvel.serve.{p}" for p in (
-        "stack", "dispatch", "result_wait", "post", "handoff")}
+        "dispatch", "result_wait", "post", "handoff")}
+    loop = {"marvel.serve.stack", "marvel.serve.resolve"}
 
     async def main():
         engine = prog.serve(mode="async", max_batch=4)
@@ -369,6 +372,7 @@ def test_serving_spans_in_a_profiler_trace(lenet_prog, tmp_path):
     m = asyncio.run(main())
     batches = m["batches"]
     assert batches == 3 and m["prefetched"] == 2
+    assert m["staged_inputs"] == batches
     (path,) = tmp_path.rglob("*.xplane.pb")
     spans = []  # ((plane, line), name, start, end, batch)
     for i, pl in enumerate(jax.profiler.ProfileData.from_file(str(path))
@@ -377,14 +381,20 @@ def test_serving_spans_in_a_profiler_trace(lenet_prog, tmp_path):
             spans += [((i, j), e.name, e.start_ns, e.start_ns + e.duration_ns,
                        {k: v for k, v in e.stats}["batch"])
                       for e in ln.events if e.name.startswith("marvel.serve.")]
-    for name in (*compute, "marvel.serve.resolve"):
+    for name in (*compute, *loop):
         ids = sorted(b for _, n, _, _, b in spans if n == name)
         assert ids == list(range(batches)), name
     (line,) = {ln for ln, n, _, _, _ in spans if n in compute}
+    (loop_line,) = {ln for ln, n, _, _, _ in spans if n in loop}
+    assert loop_line != line
     on_line = sorted((s, e) for ln, n, s, e, _ in spans
                      if ln == line and n in compute)
     assert all(e <= s2 for (_, e), (s2, _) in zip(on_line, on_line[1:]))
     start = {(n, b): s for _, n, s, _, b in spans}
+    end = {(n, b): e for _, n, _, e, b in spans}
+    for b in range(batches):  # staged before its launch
+        assert (end["marvel.serve.stack", b]
+                <= start["marvel.serve.dispatch", b])
     for b in range(1, batches):  # launched before the result before it
         assert (start["marvel.serve.dispatch", b]
                 < start["marvel.serve.result_wait", b - 1])
@@ -483,22 +493,30 @@ def test_look_ahead_launches_the_next_batch_before_the_result_read(
 
 
 def test_look_ahead_logits_are_bit_identical_to_the_sync_engine(lenet_prog):
+    """Batches stacked on the compute thread (an engine not warmed: no
+    bucket's placement is known when the three are dispatched) and
+    batches staged on the device at dispatch (a warmed engine) both give
+    the sync engine's logits, bit for bit."""
     prog, _, _, in_shape = lenet_prog
     images = _images(in_shape, 12, seed=3)
-
-    async def main():
-        async with prog.serve(mode="async", max_batch=4) as engine:
-            results = await _held_then_released(engine, images, 3)
-            return results, engine.metrics(), engine.compute.launched
-
-    results, m, launched = asyncio.run(main())
-    assert m["prefetched"] == 2 and launched == {}
     sync = prog.serve(max_batch=4)
     for i, im in enumerate(images):
         sync.submit(i, im)
     want = sync.run_until_drained()  # the same batches of four, in turn
-    for i, r in enumerate(results):
-        np.testing.assert_array_equal(r.logits, want[i].logits)
+
+    async def main(warm):
+        async with prog.serve(mode="async", max_batch=4) as engine:
+            if warm:
+                engine.warmup(in_shape)
+            results = await _held_then_released(engine, images, 3)
+            return results, engine.metrics(), engine.compute
+
+    for warm, staged in ((False, 0), (True, 3)):
+        results, m, compute = asyncio.run(main(warm))
+        assert m["prefetched"] == 2 and m["staged_inputs"] == staged
+        assert compute.launched == {} and compute.device_inputs == {}
+        for i, r in enumerate(results):
+            np.testing.assert_array_equal(r.logits, want[i].logits)
 
 
 def test_poison_pill_in_a_look_ahead_batch_is_isolated(lenet_prog):
@@ -556,6 +574,166 @@ def test_a_look_ahead_launch_that_raises_spares_the_batch_before_it(
     assert [r.label for r in results] == list(want)
     assert m["errors"] == 0 and m["retries"] == 0
     assert m["prefetched"] == 0 and launched == {} and len(calls) == 3
+
+
+def test_every_dispatched_batch_is_staged_once_warmed(lenet_prog):
+    """A warmed engine stages each batch it dispatches, full or partial,
+    one at a time or several waiting, and leaves no launched output or
+    staged input behind once drained."""
+    prog, _, _, in_shape = lenet_prog
+
+    async def main():
+        async with prog.serve(mode="async", max_batch=4) as engine:
+            engine.warmup(in_shape)
+            for n in (1, 3, 4):  # buckets 1, 4 and 4, each awaited
+                await asyncio.gather(*[engine.submit(im) for im in
+                                       _images(in_shape, n, seed=n)])
+            await _held_then_released(
+                engine, _images(in_shape, 12, seed=9), 3)
+            await engine.stop()
+            return engine.metrics(), engine.compute
+
+    m, compute = asyncio.run(main())
+    assert m["batches"] == 6 and m["completed"] == 20
+    assert m["staged_inputs"] == m["batches"]
+    assert compute.launched == {} and compute.device_inputs == {}
+
+
+@pytest.mark.timeout(120)
+def test_staging_under_a_short_switch_interval(lenet_prog):
+    """The event loop stages while the compute thread launches, with the
+    interpreter switching threads every microsecond: every batch is staged
+    once and launched from its input, no staged input or launched output
+    is left, and the answers are the reference's."""
+    import sys
+
+    prog, apply, params, in_shape = lenet_prog
+    images = _images(in_shape, 64, seed=13)
+
+    async def main():
+        async with prog.serve(mode="async", max_batch=4) as engine:
+            engine.warmup(in_shape)
+            results = await asyncio.gather(*[
+                engine.submit(im) for im in images])
+            await engine.stop()
+            return results, engine.metrics(), engine.compute
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        results, m, compute = asyncio.run(main())
+    finally:
+        sys.setswitchinterval(interval)
+    want = np.argmax(np.asarray(apply(params, np.stack(images))), axis=-1)
+    assert [r.label for r in results] == list(want)
+    assert m["completed"] == 64 and m["staged_inputs"] == m["batches"]
+    assert compute.launched == {} and compute.device_inputs == {}
+
+
+def test_kill_drops_the_staged_inputs(lenet_prog):
+    """Batches staged behind a held compute thread hold device arrays;
+    kill() drops them with the batches, and with any launched output."""
+    from repro.runtime.batching import WorkerUnavailable
+
+    prog, _, _, in_shape = lenet_prog
+
+    async def main():
+        engine = prog.serve(mode="async", max_batch=4)
+        await engine.start()
+        engine.warmup(in_shape)
+        gate = threading.Event()
+        engine._pool.submit(gate.wait)
+        futs = [engine.submit_nowait(im, uid=i)
+                for i, im in enumerate(_images(in_shape, 8, seed=11))]
+        for _ in range(5_000):
+            if len(engine.compute.device_inputs) == 2:
+                break
+            await asyncio.sleep(0.001)
+        staged = len(engine.compute.device_inputs)
+        engine.kill("drill")
+        gate.set()
+        results = await asyncio.gather(*futs, return_exceptions=True)
+        return staged, results, engine.compute
+
+    staged, results, compute = asyncio.run(main())
+    assert staged == 2
+    assert all(isinstance(r, WorkerUnavailable) for r in results)
+    assert compute.launched == {} and compute.device_inputs == {}
+
+
+def test_poison_pill_in_a_staged_batch_restacks_its_sub_batches(
+        lenet_prog, monkeypatch):
+    """uid 5 is in batch 1, staged on the device at dispatch: bisection
+    still fails exactly that request, and its retries and sub-batches
+    stack the host images again on the compute thread."""
+    from repro.runtime import cnn_server
+    from repro.runtime.batching import RetryPolicy
+    from repro.runtime.faults import FaultInjector, InjectedFault
+
+    stacks = []  # (batch, attempt, size, thread) per stack span
+
+    class Span:
+        def __init__(self, name, **tags):
+            if name == "marvel.serve.stack":
+                stacks.append((tags["batch"], tags["attempt"], tags["size"],
+                               threading.current_thread().name))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(cnn_server, "TraceAnnotation", Span)
+    prog, _, _, in_shape = lenet_prog
+    images = _images(in_shape, 12, seed=4)
+
+    async def main():
+        engine = prog.serve(
+            mode="async", max_batch=4, faults=FaultInjector(poison_uids=(5,)),
+            retry=RetryPolicy(max_retries=1, backoff_base_ms=0.1, jitter=0.0))
+        async with engine:
+            engine.warmup(in_shape)
+            results = await _held_then_released(engine, images, 3)
+        return results, engine.metrics(), engine.compute
+
+    results, m, compute = asyncio.run(main())
+    assert [i for i, r in enumerate(results)
+            if isinstance(r, Exception)] == [5]
+    assert isinstance(results[5], InjectedFault)
+    assert all(r.done for i, r in enumerate(results) if i != 5)
+    assert m["errors"] == 1 and m["staged_inputs"] == 3
+    assert compute.launched == {} and compute.device_inputs == {}
+    on_loop = [(b, a, n) for b, a, n, t in stacks
+               if not t.startswith("cnn-compute")]
+    assert on_loop == [(0, 0, 4), (1, 0, 4), (2, 0, 4)]
+    # the fault fires before a launch, so of batch 1's calls only its
+    # innocent sub-batches launch: uid 4 alone, then uids 6 and 7
+    assert [(b, a, n) for b, a, n, t in stacks
+            if t.startswith("cnn-compute")] == [(1, 4, 1), (1, 7, 2)]
+
+
+def test_a_staged_input_is_placed_as_the_sharded_executable_takes_it(
+        lenet_prog):
+    """The program is sharded (``.shard`` over a 1x1 mesh): a staged
+    batch's device array carries the input sharding of its bucket's
+    executable, and the program's answer for it is the host array's."""
+    prog, _, _, in_shape = lenet_prog
+    engine = prog.serve(mode="async", max_batch=4)
+    engine.warmup(in_shape)
+    images = _images(in_shape, 3, seed=12)
+    engine.compute.stage(images, (0, 1, 2), batch=0)
+    x, seconds = engine.compute.device_inputs[(0, 1, 2)]
+    exe = prog.executable_for(jax.ShapeDtypeStruct((4, *in_shape),
+                                                   np.float32))
+    assert x.sharding == exe.input_shardings[0][0]
+    assert x.sharding.mesh == prog.mesh and seconds > 0
+    host = batching.pad_batch(np.stack(images), 4)
+    np.testing.assert_array_equal(np.asarray(x), host)
+    np.testing.assert_array_equal(np.asarray(prog(x)), np.asarray(prog(host)))
+    labels, _, _ = engine.compute.classify(images, uids=(0, 1, 2))
+    assert engine.compute.device_inputs == {}
+    assert engine.metrics()["staged_inputs"] == 1 and len(labels) == 3
 
 
 def test_retry_after_hint_reads_compute_seconds_per_batch():

@@ -493,7 +493,7 @@ def test_supervisor_prometheus_export(lenet_prog):
     assert "marvel_serving_completed 8" in lines  # aggregate sample
     for key in ("stack_s", "dispatch_s", "result_wait_s", "post_s",
                 "queue_wait_s", "executor_wait_s", "build_s", "prefetched",
-                "ref_fallbacks"):
+                "staged_inputs", "ref_fallbacks"):
         assert f"# TYPE marvel_serving_{key} gauge" in lines
     labelled = [ln for ln in lines if 'worker="lenet5/0"' in ln]
     assert any(ln.startswith("marvel_serving_completed{") for ln in labelled)
