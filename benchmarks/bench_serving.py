@@ -4,8 +4,8 @@ Drives the full deployment path — marvel.compile -> shard() over the local
 devices -> AsyncCnnEngine — with a wave of concurrent single-image requests,
 and emits the rows the CI bench-gate consumes: requests/s, p50/p99 latency,
 batch occupancy, and the recompiles-after-warmup counter (must be 0: the
-whole point of the bucketed AOT cache).  The synchronous engine is measured
-alongside as the no-coalescing comparison point.
+whole point of the bucketed AOT cache), and the event-loop handoffs per
+batch (must be 1: the compute thread hands back whole batches).
 
 The fault rows (informational, lenet5 only) measure the control plane from
 this PR's robustness tier: throughput under injected flaky compute (degraded
@@ -71,12 +71,14 @@ def run() -> None:
         dt, warm_misses = asyncio.run(session())
         m = engine.metrics()
         recompiles = m["cache_misses"] - warm_misses
+        handoffs = m["loop_handoffs"] / max(m["batches"], 1)
         emit(
             f"serving/{name}_async_throughput", dt / REQUESTS * 1e6,
             f"req_s={REQUESTS / dt:.1f};batches={m['batches']};"
             f"occupancy={m['batch_occupancy']:.2f};"
             f"dp_shards={m['dp_shards']};"
-            f"recompiles_after_warmup={recompiles}",
+            f"recompiles_after_warmup={recompiles};"
+            f"handoffs_per_batch={handoffs:.2f}",
         )
         emit(
             f"serving/{name}_async_latency", 0.0,
@@ -84,33 +86,6 @@ def run() -> None:
             f"p99_ms={m['p99_latency_ms']:.2f};"
             f"deadline_flushes={m['deadline_flushes']};"
             f"full_flushes={m['full_flushes']}",
-        )
-
-        # sync comparison: same buckets, caller-driven, no coalescing window
-        sync = prog.serve(max_batch=MAX_BATCH)
-        sync.warmup(in_shape)
-        for uid, im in enumerate(imgs):
-            sync.submit(uid, im)
-        t0 = time.perf_counter()
-        sync.run_until_drained()
-        sdt = time.perf_counter() - t0
-        ms = sync.metrics()
-        emit(
-            f"serving/{name}_sync_throughput", sdt / REQUESTS * 1e6,
-            f"req_s={REQUESTS / sdt:.1f};batches={ms['batches']};"
-            f"occupancy={ms['batch_occupancy']:.2f}",
-        )
-
-        # the async tier must stay within a small constant of the sync
-        # plane (it adds one loop handoff per flush, never per request);
-        # handoffs_per_batch == 1 is the structural assert, the ratio row
-        # is wall-clock (informational in the gate, like every timing)
-        handoffs = m["loop_handoffs"] / max(m["batches"], 1)
-        emit(
-            f"serving/{name}_async_vs_sync", 0.0,
-            f"async_sync_ratio={sdt / dt:.3f};"
-            f"handoffs_per_batch={handoffs:.2f};"
-            f"async_req_s={REQUESTS / dt:.1f};sync_req_s={REQUESTS / sdt:.1f}",
         )
 
         if name == "lenet5":

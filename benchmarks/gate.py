@@ -16,8 +16,8 @@ the job):
   the calibrated runner's own variance estimate
   (``benchmarks/calibrate.py``), so the measured pallas-vs-ref lane
   (``benchmarks/bench_ratio.py``) gates without flaking; ratio-named
-  wall-clock metrics on rows *without* a noise floor (``async_sync_ratio``,
-  ``cache_ratio``) stay informational.
+  wall-clock metrics on rows *without* a noise floor (``cache_ratio``)
+  stay informational.
 
 Raw wall-clock metrics (``us_per_call``, ``req_s``, ``p99_ms`` ...) vary
 with the runner, so they are reported in the delta table but never gate.
@@ -96,7 +96,7 @@ def gate_direction(row_name: str, key: str,
 
     ``*_ratio`` metrics gate only when ``metrics`` carries a
     ``noise_floor`` — the calibrated-runner contract.  Rows without one
-    (``async_sync_ratio``, ``cache_ratio`` ...) are raw wall-clock and stay
+    (``cache_ratio`` ...) are raw wall-clock and stay
     informational."""
     if key in NEVER_GATE:
         return 0

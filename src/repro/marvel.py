@@ -29,15 +29,13 @@ mesh over every local device) with batch inputs sharded over the mesh's
 batch axes via :func:`repro.launch.shardings.dp_input_sharding`; every
 bucket executable is then AOT-compiled against those ``NamedSharding``
 inputs, so one program serves N chips and the compile cache still holds one
-executable per shape bucket.  ``prog.serve()`` returns the synchronous
-:class:`repro.runtime.cnn_server.CnnBatchEngine`;
-``prog.serve(mode="async")`` returns the
+executable per shape bucket.  ``prog.serve()`` returns the
 :class:`repro.runtime.cnn_server.AsyncCnnEngine` serving tier (bounded
 admission -> deadline-aware micro-batch coalescing -> DP dispatch ->
 per-request futures)::
 
     prog = marvel.compile(apply, x, params=params).shard()   # all devices
-    async with prog.serve(mode="async", max_batch=32) as engine:
+    async with prog.serve(max_batch=32) as engine:
         engine.warmup(in_shape)           # zero recompiles after this
         result = await engine.submit(image)
         engine.metrics()  # queue_depth, p50/p99 latency, batch_occupancy,
@@ -296,17 +294,16 @@ class MarvelProgram:
     def cache_size(self) -> int:
         return len(self._cache)
 
-    def serve(self, mode: str = "sync", **engine_kwargs):
+    def serve(self, mode: str = "async", **engine_kwargs):
         """A serving engine over this artifact.
 
-        CNN classifiers: ``mode="sync"`` returns the caller-driven
-        :class:`~repro.runtime.cnn_server.CnnBatchEngine`; ``mode="async"``
-        returns the :class:`~repro.runtime.cnn_server.AsyncCnnEngine`
-        serving tier (``await engine.submit(x)``).  Both drive ``__call__``
-        with bucketed batches, so serving reuses the AOT cache — one
-        executable per batch bucket — and both respect :meth:`shard`:
-        buckets round up to ``dp_shards`` and batches dispatch SPMD across
-        the mesh.
+        CNN classifiers: ``mode="async"`` (the default) returns the
+        :class:`~repro.runtime.cnn_server.AsyncCnnEngine` serving tier
+        (``await engine.submit(x)``).  It runs bucketed batches through the
+        program's bucket executables, so serving reuses the AOT cache — one
+        executable per batch bucket — and respects :meth:`shard`: buckets
+        round up to ``dp_shards`` and batches dispatch SPMD across the
+        mesh.
 
         LM classes (``*_lm``): ``mode="lm"`` returns the continuous-batching
         :class:`~repro.runtime.lm_server.AsyncLmEngine` (``await
@@ -353,18 +350,18 @@ class MarvelProgram:
                        **engine_kwargs)
         if self.model_class != "cnn":
             raise NotImplementedError(
-                f"serve() covers the cnn class (mode='sync'/'async') and LM "
+                f"serve() covers the cnn class (mode='async') and LM "
                 f"classes (mode='lm'/'lm_sync'); this program is "
                 f"{self.model_class!r}"
             )
-        from repro.runtime.cnn_server import AsyncCnnEngine, CnnBatchEngine
-
-        engines = {"sync": CnnBatchEngine, "async": AsyncCnnEngine}
-        if mode not in engines:
+        if mode != "async":
             raise ValueError(
-                f"unknown serve mode {mode!r}; choose from {sorted(engines)}"
+                f"unknown serve mode {mode!r}; choose 'async' for the cnn "
+                f"class, 'lm' or 'lm_sync' for LM classes"
             )
-        return engines[mode](self, **engine_kwargs)
+        from repro.runtime.cnn_server import AsyncCnnEngine
+
+        return AsyncCnnEngine(self, **engine_kwargs)
 
     def metrics(self) -> dict:
         """Cache, build, reference-fallback and shard counters, the

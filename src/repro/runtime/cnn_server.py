@@ -1,19 +1,14 @@
 """Batch-inference serving for CNN classifiers over a MarvelProgram.
 
-Two planes share one compute core (:class:`_BucketedCompute`):
+:class:`AsyncCnnEngine` is the serving tier: an ``asyncio`` request plane
+(bounded admission queue -> deadline-aware micro-batch coalescing -> one
+compute thread -> per-request futures) decoupled from the blocking jax
+dispatch, so thousands of in-flight requests cost one event loop, not one
+thread each.  Its compute core is :class:`_BucketedCompute`::
 
-* :class:`CnnBatchEngine` — the synchronous engine: callers submit, then
-  drive ``step()``/``run_until_drained()`` themselves.  Good for batch jobs
-  and tests.
-* :class:`AsyncCnnEngine` — the serving tier: an ``asyncio`` request plane
-  (bounded admission queue -> deadline-aware micro-batch coalescing -> one
-  compute thread -> per-request futures) decoupled from the blocking jax
-  dispatch, so thousands of in-flight requests cost one event loop, not one
-  thread each::
-
-      prog = marvel.compile(apply, x, params=params).shard(mesh)
-      async with prog.serve(mode="async", max_batch=32) as engine:
-          result = await engine.submit(image)
+    prog = marvel.compile(apply, x, params=params).shard(mesh)
+    async with prog.serve(max_batch=32) as engine:
+        result = await engine.submit(image)
 
 Batches are padded to power-of-two buckets (rounded up to the program's DP
 shard count when sharded), so a drained queue of thousands of requests
@@ -22,15 +17,15 @@ every bucket from ShapeDtypeStructs before the first request arrives.
 
 Self-healing request plane
 --------------------------
-A compute exception no longer fails every co-batched request.  Both engines
-run batches through the shared resilient path (:func:`_classify_resilient`):
-transient failures retry with exponential backoff + seeded jitter
+A compute exception does not fail every co-batched request.  Batches run
+through the resilient path (:func:`_classify_resilient`): transient
+failures retry with exponential backoff + seeded jitter
 (:class:`~repro.runtime.batching.RetryPolicy`); a batch that keeps failing
 is *bisected* to isolate the poison-pill request, so innocent requests still
-resolve and exactly the bad one fails.  The async plane additionally
-fast-fails requests whose ``deadline_ms`` expired before dispatch
-(:class:`~repro.runtime.batching.DeadlineExceeded` — no compute burned) and
-sheds load at admission with a ``retry_after_ms`` hint on
+resolve and exactly the bad one fails.  Requests whose ``deadline_ms``
+expired before dispatch fast-fail
+(:class:`~repro.runtime.batching.DeadlineExceeded` — no compute burned), and
+admission sheds load with a ``retry_after_ms`` hint on
 :class:`AdmissionError`.  Every failure mode is a counter on ``metrics()``:
 ``errors`` / ``retries`` / ``shed`` / ``deadline_failures``.  A
 :class:`~repro.runtime.faults.FaultInjector` passed as ``faults=`` drives
@@ -42,23 +37,22 @@ Tracing
 Each phase of a batch is a ``jax.profiler.TraceAnnotation`` span, inert
 unless a profiler is running, and adds its seconds to a counter on
 ``metrics()``.  Leaf spans, never nested: ``marvel.serve.stack``
-(``stack_s``: on the event loop for a batch the async engine staged, on
-the compute thread otherwise), and on the compute thread
-``marvel.serve.dispatch`` (``dispatch_s``), ``marvel.serve.result_wait``
-(``result_wait_s``: the device run and the device-to-host copy),
-``marvel.serve.post`` (``post_s``) and, async engine,
-``marvel.serve.handoff``; on the event loop ``marvel.serve.resolve``.
-Every span carries ``batch``, the id the engine gave the batch; the
-stack, dispatch, result wait and post spans also carry ``size``,
-``bucket`` and ``attempt`` (retries and bisection call the program
-again).  The async engine also counts ``queue_wait_s`` (admission to
+(``stack_s``: on the event loop for a staged batch, on the compute thread
+otherwise), and on the compute thread ``marvel.serve.dispatch``
+(``dispatch_s``), ``marvel.serve.result_wait`` (``result_wait_s``: the
+device run and the device-to-host copy), ``marvel.serve.post``
+(``post_s``) and ``marvel.serve.handoff``; on the event loop
+``marvel.serve.resolve``.  Every span carries ``batch``, the id the engine
+gave the batch; the stack, dispatch, result wait and post spans also carry
+``size``, ``bucket`` and ``attempt`` (retries and bisection call the
+program again).  The engine also counts ``queue_wait_s`` (admission to
 dispatch, summed over requests) and ``executor_wait_s`` (dispatch to the
 compute thread taking the batch, summed over batches); ``build_s`` is the
 program's seconds compiling bucket executables, and ``ref_fallbacks`` its
 dispatch sites that fell back to the jnp oracle while those were traced.
 
-Staging (async engine)
-----------------------
+Staging
+-------
 When the event loop coalesces a batch, it stacks and pads the images and
 starts their transfer to the device (``jax.device_put`` with the input
 sharding of the bucket's executable, not waited on) before handing the
@@ -66,18 +60,18 @@ batch to the compute thread.  The batch's launch then only calls the
 program on the device array.  A batch is staged once its bucket's
 executable is known (warmed, or launched once); a launch that finds no
 staged input (the first batch of a bucket not warmed, retries, bisection
-sub-batches, the sync engine) stacks on the compute thread.
-``staged_inputs`` on ``metrics()`` counts the launches that found one.
+sub-batches) stacks on the compute thread.  ``staged_inputs`` on
+``metrics()`` counts the launches that found one.
 
-Look-ahead (async engine)
--------------------------
+Look-ahead
+----------
 When the compute thread takes up batch N and batch N+1 is already waiting
 behind it, it launches N (unless already launched), then N+1, and only
 then waits on N: a launch is the program call (and, for a batch not
 staged, the stack before it) plus the start of the result's copy to the
 host.  N+1's executable is so queued on the device behind N.  The depth
-is one batch; with no batch waiting, the thread runs each batch in turn
-as the sync engine does.  The compute thread's spans then read, per
+is one batch; with no batch waiting, the thread launches, waits on and
+posts each batch in turn.  The compute thread's spans then read, per
 batch taken up: ``dispatch`` of N+1, then ``result_wait``, ``post`` and
 ``handoff`` of N (``dispatch`` of N too, where no look-ahead launched
 it), each batch leaving each span once.  ``prefetched`` on ``metrics()``
@@ -121,9 +115,10 @@ class CnnRequest:
 
 
 class _BucketedCompute:
-    """program + buckets + the batched classify step (shared by both
-    engines).  Buckets are rounded up to the program's DP shard count so a
-    sharded program always sees batch dims its mesh divides."""
+    """program + buckets + the batched classify step of
+    :class:`AsyncCnnEngine`.  Buckets are rounded up to the program's DP
+    shard count so a sharded program always sees batch dims its mesh
+    divides."""
 
     def __init__(self, program, max_batch: int = 8,
                  buckets: tuple[int, ...] = (),
@@ -319,102 +314,6 @@ def _classify_resilient(compute: _BucketedCompute, reqs: list[CnnRequest],
         return [("err", err)] * len(sub)
 
     return solve(reqs, retry.max_splits), retries
-
-
-class CnnBatchEngine:
-    """Queue -> bucketed batches -> MarvelProgram -> per-request results
-    (synchronous plane; the caller drives ``step()``)."""
-
-    def __init__(self, program, max_batch: int = 8,
-                 buckets: tuple[int, ...] = (),
-                 max_pending: int | None = None,
-                 faults: faults.FaultInjector | None = None,
-                 retry: batching.RetryPolicy | None = None):
-        self._metrics = batching.EngineMetrics()
-        self.compute = _BucketedCompute(program, max_batch, buckets,
-                                        faults_injector=faults,
-                                        metrics=self._metrics)
-        self.retry = retry or batching.RetryPolicy()
-        self.queue = batching.BoundedQueue(capacity=max_pending)
-        self.results: dict[int, CnnRequest] = {}
-
-    @property
-    def program(self):
-        return self.compute.program
-
-    @property
-    def buckets(self) -> tuple[int, ...]:
-        return self.compute.buckets
-
-    @property
-    def max_batch(self) -> int:
-        return self.compute.max_batch
-
-    @property
-    def batches_run(self) -> int:
-        return self._metrics.batches
-
-    def warmup(self, in_shape: tuple[int, ...], dtype="float32") -> None:
-        self.compute.warmup(in_shape, dtype)
-
-    def submit(self, uid: int, image) -> CnnRequest:
-        req = CnnRequest(uid=uid, image=np.asarray(image))
-        self.queue.push(req)  # AdmissionError surfaces to the caller
-        self._metrics.submitted += 1
-        return req
-
-    def step(self) -> list[CnnRequest]:
-        """Serve one batch: up to ``max_batch`` queued requests, padded to
-        the smallest bucket so the AOT cache hits.
-
-        Compute exceptions are contained: the failing request(s) resolve
-        with ``.error`` set (after retry/bisection), everything else in the
-        batch succeeds, and the engine stays serviceable — ``step()`` only
-        raises for :class:`~repro.runtime.faults.WorkerDeath` (the worker
-        itself is gone, which a caller of ``run_until_drained`` must see).
-        """
-        if not self.queue:
-            return []
-        t0 = time.perf_counter()
-        reqs = self.queue.pop_up_to(self.max_batch)
-        outcomes, retries = _classify_resilient(
-            self.compute, reqs, self.retry, batch=self._metrics.batches)
-        self._metrics.retries += retries
-        bucket = batching.bucket_for(self.buckets, len(reqs))
-        self._metrics.observe_batch(len(reqs), bucket)
-        ms = (time.perf_counter() - t0) * 1e3
-        for req, out in zip(reqs, outcomes):
-            req.latency_ms = ms
-            if out[0] == "err":
-                req.error = out[1]
-                self._metrics.errors += 1
-            else:
-                req.label = out[1]
-                req.probs = out[2]
-                req.logits = out[3]
-                req.done = True
-                self._metrics.completed += 1
-                self._metrics.observe_latency(ms)
-            self.results[req.uid] = req
-        return reqs
-
-    @property
-    def pending(self) -> int:
-        return len(self.queue)
-
-    def run_until_drained(self, max_steps: int = 10_000) -> dict:
-        steps = 0
-        while self.queue and steps < max_steps:
-            self.step()
-            steps += 1
-        return self.results
-
-    def metrics(self) -> dict:
-        """The serving metrics surface (program cache counters included)."""
-        self._metrics.rejected = self.queue.rejected
-        return self._metrics.snapshot(
-            queue_depth=len(self.queue), **_program_metrics(self.program)
-        )
 
 
 class AsyncCnnEngine:

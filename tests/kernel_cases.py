@@ -1,6 +1,7 @@
 """Shared kernel-conformance cases: builders, quant mirrors, tolerances.
 
-Single source of truth for the differential suite (test_conformance.py) and
+Single source of truth for the differential suite (conformance_runners.py,
+test_conformance.py and test_conformance_fuzz_*.py) and
 the per-kernel test files (test_fused_conv.py, test_depthwise.py), so nobody
 hand-rolls a slightly-different int8 quantization mirror or tolerance again.
 

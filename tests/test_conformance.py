@@ -1,6 +1,7 @@
 """Differential kernel-conformance suite: every Pallas impl vs its oracle.
 
-Three layers, all driven off the shared case library in kernel_cases.py:
+Three layers, all driven off the runners in ``conformance_runners.py`` and
+the shared case library in ``kernel_cases.py``:
 
 1. **Completeness** — every pattern registered with a ``pallas`` impl in
    repro.core.dispatch must have conformance cases here; a new kernel that
@@ -9,182 +10,27 @@ Three layers, all driven off the shared case library in kernel_cases.py:
    grid per kernel family (odd sizes, >128-lane channel counts, every
    supported act), wrapper output vs the bit-faithful quantized oracle,
    tolerances derived from the accumulator dtype.  Runs in every lane.
-3. **Hypothesis fuzzing** — randomized shapes/strides/acts over the same
-   runners (small budget in the fast lane, the full grid under ``-m slow``
-   in CI's tests-slow lane).  Skipped cleanly where hypothesis isn't
-   installed.
+3. **Hypothesis fuzzing** — seeded randomized shapes/strides/acts over the
+   same runners, in ``test_conformance_fuzz_*.py`` (two kernels a file, so
+   that xdist's ``--dist loadfile`` spreads them over workers).
 
 Fallback-guard cases assert that inputs a kernel declines (grouped weights,
 exotic padding, degenerate outputs, mis-shaped residuals, unsupported pool
 windows) still *dispatch* — they take the jnp fallback and match the
 baseline, instead of crashing.
 """
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import kernel_cases as kc
+from conformance_runners import FUZZ_COVERED, FUZZ_FILES, RUNNERS
 from repro.core import dispatch
 from repro.kernels import ops, ref
 from repro.models import cnn
-
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:  # container without hypothesis: fuzz layer skips
-    HAVE_HYPOTHESIS = False
-
-
-# ---------------------------------------------------------------------------
-# conformance runners: one per registered pallas pattern
-# ---------------------------------------------------------------------------
-
-
-def run_mac_matmul(seed=0, m=64, k=96, n=32):
-    from repro.kernels.mac_matmul import mac_matmul_int8
-
-    x, w, s = kc.mac_case(seed, m, k, n)
-    got = mac_matmul_int8(x, w, s)
-    want = ref.mac_matmul_int8_ref(x, w, s)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               **kc.tol_from_acc(jnp.int32, k))
-
-
-def run_fused_conv(seed=0, h=13, w_sp=11, cin=5, cout=9, k=3, stride=1,
-                   padding="SAME", act="relu", residual=False):
-    x, w, b, s, t = kc.conv_case(seed, h, w_sp, cin, cout, k)
-    res = None
-    if residual:
-        want_shape = jax.eval_shape(
-            lambda a, b: ref.fused_conv_ref(a, b, None, stride=stride,
-                                            padding=padding), x, w,
-        ).shape
-        res = jax.random.normal(jax.random.PRNGKey(seed + 1), want_shape)
-    got = ops._pallas_fused_conv(x, w, b, stride=stride, padding=padding,
-                                 groups=1, act=act, scale=s, shift=t,
-                                 residual=res)
-    want = kc.quant_conv_oracle(x, w, b, s, t, stride=stride,
-                                padding=padding, act=act, residual=res)
-    assert got.shape == want.shape
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               **kc.tol_from_acc(jnp.int32, k * k * cin))
-
-
-def run_depthwise(seed=0, h=13, w_sp=11, c=5, stride=1, padding="SAME",
-                  act="relu"):
-    x, w, b, s, t = kc.dw_case(seed, h, w_sp, c)
-    got = ops._pallas_depthwise_conv(x, w, b, stride=stride, padding=padding,
-                                     act=act, scale=s, shift=t)
-    want = kc.quant_dw_oracle(x, w, b, s, t, stride=stride, padding=padding,
-                              act=act)
-    assert got.shape == want.shape
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               **kc.tol_from_acc(jnp.int32, 9))
-
-
-def run_sep_block(seed=0, h=13, w_sp=11, c=5, cout=9, stride=1,
-                  dw_act="relu", pw_act="none"):
-    x, wd, wp, ds, dt, ps, pt = kc.sep_case(seed, h, w_sp, c, cout)
-    got = ops._pallas_sep_block(x, wd, wp, stride=stride, dw_scale=ds,
-                                dw_shift=dt, dw_act=dw_act, pw_scale=ps,
-                                pw_shift=pt, pw_act=pw_act)
-    want = kc.quant_sep_oracle(x, wd, wp, ds, dt, ps, pt, stride=stride,
-                               dw_act=dw_act, pw_act=pw_act)
-    assert got.shape == want.shape
-    # dw stage: int32 acc; pw stage: f32 acc over C — 2x slack for the chain
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               **kc.tol_from_acc(jnp.int32, c, slack=2.0))
-
-
-def run_matmul_epilogue(seed=0, m=37, k=64, n=48, act="relu",
-                        dtype=jnp.float32, residual=False, affine=True):
-    x, w, b, r = kc.matmul_case(seed, m, k, n, dtype)
-    s = 0.5 + jax.random.uniform(jax.random.PRNGKey(seed + 2), (n,))
-    got = ops._pallas_matmul_epilogue(
-        x, w, b, act=act, scale=s if affine else None, shift=None,
-        residual=r if residual else None,
-    )
-    want = ref.matmul_epilogue_ref(
-        x, w, b, act=act, scale=s if affine else None, shift=None,
-        residual=r if residual else None,
-    )
-    assert got.shape == want.shape
-    # f32 accumulator, but a low-precision operand dtype floors the tol
-    tol = kc.tol_from_acc(dtype, k)
-    np.testing.assert_allclose(np.asarray(got, np.float32),
-                               np.asarray(want, np.float32), **tol)
-
-
-def run_pool(seed=0, h=13, w_sp=11, c=5, op="max", k=2, stride=2,
-             dtype=jnp.float32):
-    x = kc.pool_case(seed, h, w_sp, c, dtype)
-    got = ops._pallas_pool(x, op=op, k=k, stride=stride)
-    want = ref.pool_ref(x, op=op, k=k, stride=stride)
-    assert got.shape == want.shape and got.dtype == want.dtype
-    window = h * w_sp if op == "global_avg" else k * k
-    if op == "max":
-        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
-    else:
-        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                                   **kc.tol_from_acc(jnp.float32, window))
-
-
-def run_residual_rmsnorm(seed=0, rows=33, d=96):
-    res, x, scale = kc.rmsnorm_case(seed, rows, d)
-    new_res, normed = ops._pallas_residual_rmsnorm(res, x, scale)
-    want_res, want_norm = ref.residual_rmsnorm_ref(res, x, scale)
-    tol = kc.tol_from_acc(jnp.float32, d)
-    np.testing.assert_allclose(np.asarray(new_res), np.asarray(want_res),
-                               **tol)
-    np.testing.assert_allclose(np.asarray(normed), np.asarray(want_norm),
-                               **tol)
-
-
-def run_flash_attention(seed=0, b=1, sq=64, kheads=2, g=2, dh=16,
-                        int8_kv=False):
-    from repro.models.layers import _flash_attention_ref
-
-    q, k, v, k_s, v_s = kc.attn_case(seed, b, sq, kheads, g, dh,
-                                     int8_kv=int8_kv)
-    got = ops._pallas_flash_attention(q, k, v, causal=True,
-                                      k_scale=k_s, v_scale=v_s)
-    want = _flash_attention_ref(q, k, v, causal=True,
-                                k_scale=k_s, v_scale=v_s)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               **kc.tol_from_acc(jnp.float32, sq, slack=4.0))
-
-
-def run_wkv_chunk(seed=0, b=1, s=32, heads=2, n=8, chunk=16):
-    r, k, v, lw, u, s0 = kc.wkv_case(seed, b, s, heads, n)
-    got, got_state = ops._pallas_wkv_chunk(r, k, v, lw, u, s0, chunk)
-    want, want_state = ref.wkv_ref_sequential(r, k, v, lw, u, s0)
-    tol = kc.tol_from_acc(jnp.float32, s, slack=8.0)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **tol)
-    np.testing.assert_allclose(np.asarray(got_state), np.asarray(want_state),
-                               **tol)
-
-
-# every registered pallas pattern -> its conformance runner
-RUNNERS = {
-    "mac_matmul_int8": run_mac_matmul,
-    "fused_conv": run_fused_conv,
-    "depthwise_conv": run_depthwise,
-    "sep_block": run_sep_block,
-    "matmul_epilogue": run_matmul_epilogue,
-    "pool": run_pool,
-    "residual_rmsnorm": run_residual_rmsnorm,
-    "flash_attention": run_flash_attention,
-    "wkv_chunk": run_wkv_chunk,
-}
-
-
-# patterns with a dedicated fuzz lane (all four LM kernels included; only
-# sep_block rides solely the deterministic grid + guards)
-FUZZ_COVERED = (
-    "fused_conv", "depthwise_conv", "pool", "matmul_epilogue",
-    "mac_matmul_int8", "residual_rmsnorm", "flash_attention", "wkv_chunk",
-)
 
 
 def test_every_registered_pallas_impl_has_conformance_cases():
@@ -194,7 +40,7 @@ def test_every_registered_pallas_impl_has_conformance_cases():
     missing = registered - set(RUNNERS)
     assert not missing, (
         f"registered pallas impls without conformance cases: {sorted(missing)}"
-        " — add a runner to tests/test_conformance.py::RUNNERS"
+        " — add a runner to tests/conformance_runners.py::RUNNERS"
     )
     # every LM kernel has grid AND fuzz coverage, not just a runner
     gridded = {impl for impl, _ in GRID}
@@ -202,8 +48,12 @@ def test_every_registered_pallas_impl_has_conformance_cases():
                   "wkv_chunk"}
     assert lm_kernels <= gridded
     assert lm_kernels <= set(FUZZ_COVERED) <= set(RUNNERS)
-    if HAVE_HYPOTHESIS:
-        assert len(_FUZZERS) == len(FUZZ_COVERED)
+    assert len(set(FUZZ_COVERED)) == len(FUZZ_COVERED)
+    # every fuzz file is in the table, and every file the table names exists
+    here = os.path.dirname(__file__)
+    on_disk = {f for f in os.listdir(here)
+               if f.startswith("test_conformance_fuzz_") and f.endswith(".py")}
+    assert on_disk == set(FUZZ_FILES)
 
 
 # ---------------------------------------------------------------------------
@@ -390,121 +240,6 @@ GUARDS = [
 @pytest.mark.parametrize("guard", GUARDS, ids=lambda g: g.__name__)
 def test_fallback_guards_dispatch_not_crash(guard):
     guard()
-
-
-# ---------------------------------------------------------------------------
-# hypothesis fuzzing (fast budget here; full grid in the slow lane)
-# ---------------------------------------------------------------------------
-
-if HAVE_HYPOTHESIS:
-    _conv_params = st.tuples(
-        st.integers(0, 10_000),                      # seed
-        st.integers(5, 18), st.integers(5, 18),      # h, w
-        st.integers(1, 12), st.integers(1, 12),      # cin, cout
-        st.sampled_from([1, 2, 3, 5]),               # k
-        st.sampled_from([1, 2]),                     # stride
-        st.sampled_from(["SAME", "VALID"]),
-        st.sampled_from(["none", "relu", "relu6"]),
-        st.booleans(),                               # residual
-    )
-    _dw_params = st.tuples(
-        st.integers(0, 10_000), st.integers(5, 16), st.integers(5, 16),
-        st.integers(1, 12), st.sampled_from([1, 2]),
-        st.sampled_from(["SAME", "VALID"]),
-        st.sampled_from(["none", "relu", "relu6"]),
-    )
-    _pool_params = st.tuples(
-        st.integers(0, 10_000), st.integers(2, 20), st.integers(2, 20),
-        st.integers(1, 12),
-        st.sampled_from(["max", "avg", "global_avg"]),
-        st.sampled_from([2, 3]), st.sampled_from([1, 2, 3]),
-        st.sampled_from([jnp.float32, jnp.int8]),
-    )
-    _mm_params = st.tuples(
-        st.integers(0, 10_000), st.integers(1, 40), st.integers(1, 70),
-        st.integers(1, 40), st.sampled_from(["none", "relu", "silu"]),
-        st.booleans(),
-    )
-    _mac_params = st.tuples(
-        st.integers(0, 10_000), st.integers(1, 150), st.integers(1, 300),
-        st.integers(1, 150),
-    )
-    _rms_params = st.tuples(
-        st.integers(0, 10_000), st.integers(1, 140), st.integers(8, 300),
-    )
-    _attn_params = st.tuples(
-        st.integers(0, 10_000), st.sampled_from([1, 2]),
-        st.sampled_from([16, 33, 64, 130]),            # sq (crosses bq=128)
-        st.integers(1, 3), st.integers(1, 3),          # kv heads, group size
-        st.sampled_from([8, 16, 32]),                  # dh
-        st.booleans(),                                 # int8-KV path
-    )
-    _wkv_params = st.tuples(
-        st.integers(0, 10_000), st.sampled_from([1, 2]),
-        st.integers(1, 3), st.sampled_from([4, 8, 16]),  # heads, n
-        st.sampled_from([4, 8, 16]), st.integers(1, 4),  # chunk, n_chunks
-    )
-
-    def _fuzz_conv(p):
-        seed, h, w, cin, cout, k, stride, padding, act, res = p
-        if k > min(h, w):  # degenerate handled by the guard tests
-            padding = "SAME"
-        run_fused_conv(seed, h, w, cin, cout, k, stride, padding, act, res)
-
-    def _fuzz_dw(p):
-        seed, h, w, c, stride, padding, act = p
-        run_depthwise(seed, h, w, c, stride, padding, act)
-
-    def _fuzz_pool(p):
-        seed, h, w, c, op, k, stride, dtype = p
-        run_pool(seed, h, w, c, op, k, stride, dtype)
-
-    def _fuzz_mm(p):
-        seed, m, k, n, act, res = p
-        run_matmul_epilogue(seed, m, k, n, act, residual=res)
-
-    def _fuzz_mac(p):
-        run_mac_matmul(*p)
-
-    def _fuzz_rmsnorm(p):
-        run_residual_rmsnorm(*p)
-
-    def _fuzz_attn(p):
-        seed, b, sq, kheads, g, dh, int8_kv = p
-        run_flash_attention(seed, b, sq, kheads, g, dh, int8_kv=int8_kv)
-
-    def _fuzz_wkv(p):
-        seed, b, heads, n, chunk, nc = p
-        run_wkv_chunk(seed, b, chunk * nc, heads, n, chunk)
-
-    _FUZZERS = [(_fuzz_conv, _conv_params), (_fuzz_dw, _dw_params),
-                (_fuzz_pool, _pool_params), (_fuzz_mm, _mm_params),
-                (_fuzz_mac, _mac_params), (_fuzz_rmsnorm, _rms_params),
-                (_fuzz_attn, _attn_params), (_fuzz_wkv, _wkv_params)]
-
-    def _make(fuzzer, params, max_examples):
-        @settings(max_examples=max_examples, deadline=None)
-        @given(params)
-        def t(p):
-            fuzzer(p)
-        return t
-
-    @pytest.mark.parametrize("i", range(len(_FUZZERS)),
-                             ids=[f.__name__ for f, _ in _FUZZERS])
-    def test_conformance_fuzz_fast(i):
-        fuzzer, params = _FUZZERS[i]
-        _make(fuzzer, params, 8)()
-
-    @pytest.mark.slow
-    @pytest.mark.parametrize("i", range(len(_FUZZERS)),
-                             ids=[f.__name__ for f, _ in _FUZZERS])
-    def test_conformance_fuzz_full(i):
-        fuzzer, params = _FUZZERS[i]
-        _make(fuzzer, params, 60)()
-else:  # keep the skip visible in every lane's report
-    @pytest.mark.skip(reason="hypothesis not installed; fuzz layer runs in CI")
-    def test_conformance_fuzz_fast():
-        pass
 
 
 # ---------------------------------------------------------------------------

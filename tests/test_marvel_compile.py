@@ -7,6 +7,7 @@ split by shape, extension resolution is baked at trace time, unknown
 backends raise, rewrite failures warn, and the CNN batch-inference path
 serves real requests off the artifact.
 """
+import asyncio
 import warnings
 
 import jax
@@ -237,7 +238,19 @@ def test_run_marvel_flow_accepts_shape_structs():
 # ---------------------------------------------------------------------------
 
 
-def test_cnn_batch_engine_serves_off_the_artifact():
+def _serve_wave(engine, images, first_uid=0):
+    """Submit ``images`` with consecutive uids before the batcher runs, so
+    they coalesce into full batches, and await every result."""
+    async def main():
+        async with engine:
+            return await asyncio.gather(*[
+                engine.submit_nowait(im, uid=first_uid + i)
+                for i, im in enumerate(images)])
+
+    return asyncio.run(main())
+
+
+def test_cnn_engine_serves_off_the_artifact():
     init, apply, in_shape = get_cnn("lenet5")
     params = init(jax.random.PRNGKey(0))
     x = jnp.zeros((1, *in_shape))
@@ -246,9 +259,7 @@ def test_cnn_batch_engine_serves_off_the_artifact():
     rng = np.random.default_rng(0)
     imgs = [rng.standard_normal(in_shape).astype(np.float32)
             for _ in range(6)]
-    for i, im in enumerate(imgs):
-        engine.submit(i, im)
-    results = engine.run_until_drained()
+    results = _serve_wave(engine, imgs)
     assert len(results) == 6 and engine.batches_run == 2
     ref = np.asarray(apply(params, jnp.stack(imgs)))
     want = np.argmax(ref, axis=-1)
@@ -259,13 +270,11 @@ def test_cnn_batch_engine_serves_off_the_artifact():
     assert prog.cache_size == 2
     # a second wave of the same sizes recompiles nothing
     misses = prog.cache_misses
-    for i, im in enumerate(imgs):
-        engine.submit(100 + i, im)
-    engine.run_until_drained()
+    _serve_wave(engine, imgs, first_uid=100)
     assert prog.cache_misses == misses
 
 
-def test_cnn_batch_engine_warmup_precompiles_buckets():
+def test_cnn_engine_warmup_precompiles_buckets():
     init, apply, in_shape = get_cnn("lenet5")
     params = init(jax.random.PRNGKey(0))
     prog = marvel.compile(apply, jnp.zeros((1, *in_shape)), params=params,
@@ -273,8 +282,7 @@ def test_cnn_batch_engine_warmup_precompiles_buckets():
     engine = prog.serve(max_batch=4)  # buckets 1, 2, 4
     engine.warmup(in_shape)
     assert prog.cache_size == 3 and prog.cache_misses == 3
-    engine.submit(0, np.zeros(in_shape, np.float32))
-    engine.step()
+    _serve_wave(engine, [np.zeros(in_shape, np.float32)])
     assert prog.cache_misses == 3 and prog.cache_hits == 1
 
 

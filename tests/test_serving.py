@@ -18,7 +18,7 @@ import pytest
 
 from repro import marvel
 from repro.models.cnn import get_cnn
-from repro.runtime import batching
+from repro.runtime import batching, cnn_server
 from repro.runtime.batching import AdmissionError
 
 
@@ -260,17 +260,27 @@ def test_warmup_means_zero_recompiles_under_traffic(lenet_prog):
     assert m["cache_hits"] >= m["batches"]
 
 
-def test_sync_engine_admission_and_metrics(lenet_prog):
+def test_admission_and_metrics_after_the_drain(lenet_prog):
+    """``max_pending`` bounds admitted-but-unresolved requests: a third
+    submit bounces; after the drain the counters read two completed, one
+    rejected, nothing queued, and capacity is back."""
     prog, _, _, in_shape = lenet_prog
-    engine = prog.serve(max_batch=4, max_pending=2)
-    engine.submit(0, np.zeros(in_shape, np.float32))
-    engine.submit(1, np.zeros(in_shape, np.float32))
-    with pytest.raises(AdmissionError):
-        engine.submit(2, np.zeros(in_shape, np.float32))
-    engine.run_until_drained()
-    m = engine.metrics()
+    image = np.zeros(in_shape, np.float32)
+
+    async def main():
+        async with prog.serve(max_batch=4, max_pending=2) as engine:
+            futs = [engine.submit_nowait(image), engine.submit_nowait(image)]
+            with pytest.raises(AdmissionError):
+                engine.submit_nowait(image)
+            await asyncio.gather(*futs)
+            drained = engine.metrics(), engine.outstanding
+            await engine.submit(image)
+            return drained, engine.metrics()
+
+    (m, outstanding), after = asyncio.run(main())
     assert m["completed"] == 2 and m["rejected"] == 1
-    assert m["queue_depth"] == 0
+    assert m["queue_depth"] == 0 and outstanding == 0
+    assert after["completed"] == 3 and after["rejected"] == 1
 
 
 def test_submit_after_stop_raises_instead_of_hanging(lenet_prog):
@@ -436,8 +446,6 @@ def test_look_ahead_launches_the_next_batch_before_the_result_read(
     and dispatch come before batch N's result read, and ``prefetched``
     counts them; one batch at a time, each batch runs stack, dispatch,
     result wait, post, handoff in turn and ``prefetched`` stays 0."""
-    from repro.runtime import cnn_server
-
     calls = []
 
     class Span:
@@ -492,17 +500,18 @@ def test_look_ahead_launches_the_next_batch_before_the_result_read(
     assert calls == launch(0) + finish(0) + launch(1) + finish(1)
 
 
-def test_look_ahead_logits_are_bit_identical_to_the_sync_engine(lenet_prog):
+def test_look_ahead_logits_are_bit_identical_to_the_program(lenet_prog):
     """Batches stacked on the compute thread (an engine not warmed: no
     bucket's placement is known when the three are dispatched) and
     batches staged on the device at dispatch (a warmed engine) both give
-    the sync engine's logits, bit for bit."""
+    the program's logits on the same padded batches of four, bit for
+    bit."""
     prog, _, _, in_shape = lenet_prog
     images = _images(in_shape, 12, seed=3)
-    sync = prog.serve(max_batch=4)
-    for i, im in enumerate(images):
-        sync.submit(i, im)
-    want = sync.run_until_drained()  # the same batches of four, in turn
+    want = np.concatenate([
+        np.asarray(prog(batching.pad_batch(np.stack(images[i:i + 4]), 4)))
+        for i in range(0, 12, 4)
+    ])
 
     async def main(warm):
         async with prog.serve(mode="async", max_batch=4) as engine:
@@ -516,7 +525,7 @@ def test_look_ahead_logits_are_bit_identical_to_the_sync_engine(lenet_prog):
         assert m["prefetched"] == 2 and m["staged_inputs"] == staged
         assert compute.launched == {} and compute.device_inputs == {}
         for i, r in enumerate(results):
-            np.testing.assert_array_equal(r.logits, want[i].logits)
+            np.testing.assert_array_equal(r.logits, want[i])
 
 
 def test_poison_pill_in_a_look_ahead_batch_is_isolated(lenet_prog):
@@ -666,7 +675,6 @@ def test_poison_pill_in_a_staged_batch_restacks_its_sub_batches(
     """uid 5 is in batch 1, staged on the device at dispatch: bisection
     still fails exactly that request, and its retries and sub-batches
     stack the host images again on the compute thread."""
-    from repro.runtime import cnn_server
     from repro.runtime.batching import RetryPolicy
     from repro.runtime.faults import FaultInjector, InjectedFault
 
@@ -773,8 +781,10 @@ def test_stop_drains_a_batch_whose_callbacks_are_still_queued():
 
 def test_serve_mode_validation(lenet_prog):
     prog, _, _, _ = lenet_prog
-    with pytest.raises(ValueError, match="sync"):
-        prog.serve(mode="threads")
+    for mode in ("threads", "sync"):
+        with pytest.raises(ValueError, match="'async'"):
+            prog.serve(mode=mode)
+    assert isinstance(prog.serve(), cnn_server.AsyncCnnEngine)
 
 
 @pytest.mark.slow
@@ -825,9 +835,13 @@ def test_dp_smoke_across_local_devices():
     ndev = len(jax.devices())
     assert prog.dp_shards == ndev
     engine = prog.serve(max_batch=2 * ndev)
-    assert all(b % ndev == 0 for b in engine.buckets)
+    assert all(b % ndev == 0 for b in engine.compute.buckets)
     engine.warmup(in_shape)
-    for i in range(2 * ndev + 1):
-        engine.submit(i, np.zeros(in_shape, np.float32))
-    results = engine.run_until_drained()
-    assert len(results) == 2 * ndev + 1
+
+    async def main():
+        async with engine:
+            return await engine.submit_wave(
+                [np.zeros(in_shape, np.float32)] * (2 * ndev + 1))
+
+    results = asyncio.run(main())
+    assert len(results) == 2 * ndev + 1 and all(r.done for r in results)

@@ -239,60 +239,61 @@ def test_admission_shed_carries_retry_after_hint(lenet_prog):
     assert m["completed"] == 2
 
 
-def test_worker_death_fails_unresolved_with_worker_unavailable(lenet_prog):
+@pytest.mark.parametrize("served_before", [1, 0],
+                         ids=["after_a_served_batch", "on_the_first_batch"])
+def test_worker_death_fails_unresolved_with_worker_unavailable(
+        lenet_prog, served_before):
+    """The worker dies on the attempt after ``served_before`` served ones:
+    every unresolved request fails with WorkerUnavailable and the engine
+    stops; batches served before the death stay served."""
     prog, _, _, in_shape = lenet_prog
-    inj = FaultInjector(die_after_attempts=1)
+    inj = FaultInjector(die_after_attempts=served_before)
 
     async def main():
         engine = prog.serve(mode="async", max_batch=4, max_delay_ms=1.0,
                             faults=inj,
                             retry=RetryPolicy(max_retries=0, **FAST_RETRY))
         await engine.start()
-        first = await engine.submit_wave(_images(in_shape, 4))  # attempt 1 ok
+        first = []
+        if served_before:  # attempt 1 ok
+            first = await engine.submit_wave(_images(in_shape, 4))
         futs = [engine.submit_nowait(im) for im in _images(in_shape, 4)]
         second = await asyncio.gather(*futs, return_exceptions=True)
         return first, second, engine
 
     first, second, engine = asyncio.run(main())
-    assert all(r.done for r in first)
+    assert len(first) == 4 * served_before and all(r.done for r in first)
     assert all(isinstance(r, WorkerUnavailable) for r in second)
     assert not engine.is_alive
     assert inj.injected["death"] == 1
 
 
-# ---------------------------------------------------------------------------
-# layer 1: sync engine containment
-# ---------------------------------------------------------------------------
-
-
-def test_sync_engine_contains_compute_errors(lenet_prog):
+def test_engine_contains_compute_errors(lenet_prog):
+    """A poisoned uid fails alone, its batch-mates resolve, and the engine
+    serves the next wave."""
     prog, _, _, in_shape = lenet_prog
-    engine = prog.serve(max_batch=4, faults=FaultInjector(poison_uids=(1,)),
-                        retry=RetryPolicy(max_retries=1, **FAST_RETRY))
-    for uid, im in enumerate(_images(in_shape, 3)):
-        engine.submit(uid, im)
-    reqs = engine.step()  # must NOT raise: the error is contained
-    assert len(reqs) == 3
-    by_uid = {r.uid: r for r in reqs}
-    assert isinstance(by_uid[1].error, InjectedFault) and not by_uid[1].done
-    assert by_uid[0].done and by_uid[2].done
-    m = engine.metrics()
-    assert m["errors"] == 1 and m["completed"] == 2
+
+    async def main():
+        engine = prog.serve(max_batch=4,
+                            faults=FaultInjector(poison_uids=(1,)),
+                            retry=RetryPolicy(max_retries=1, **FAST_RETRY))
+        async with engine:
+            # queued before the batcher runs: the three share one batch
+            futs = [engine.submit_nowait(im, uid=uid)
+                    for uid, im in enumerate(_images(in_shape, 3))]
+            wave = await asyncio.gather(*futs, return_exceptions=True)
+            mid = engine.metrics()
+            nxt = await engine.submit(_images(in_shape, 1)[0], uid=10)
+        return wave, mid, nxt, engine.metrics()
+
+    wave, mid, nxt, m = asyncio.run(main())
+    assert isinstance(wave[1], InjectedFault)
+    assert wave[0].done and wave[2].done
+    assert mid["errors"] == 1 and mid["completed"] == 2
+    assert mid["batches"] == 1  # the three shared one batch
     # ...and the engine stays serviceable
-    engine.submit(10, _images(in_shape, 1)[0])
-    results = engine.run_until_drained()
-    assert results[10].done
-    assert engine.metrics()["completed"] == 3
-
-
-def test_sync_engine_propagates_worker_death(lenet_prog):
-    prog, _, _, in_shape = lenet_prog
-    engine = prog.serve(max_batch=4,
-                        faults=FaultInjector(die_after_attempts=0),
-                        retry=RetryPolicy(max_retries=0, **FAST_RETRY))
-    engine.submit(0, _images(in_shape, 1)[0])
-    with pytest.raises(WorkerDeath):
-        engine.run_until_drained()
+    assert nxt.done and nxt.uid == 10
+    assert m["completed"] == 3
 
 
 # ---------------------------------------------------------------------------
